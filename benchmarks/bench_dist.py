@@ -21,7 +21,6 @@ from .common import measure, report
 def run(quick: bool = False):
     import jax
     import jax.numpy as jnp
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     from repro.dist import compression, dframe, pipeline
@@ -61,9 +60,9 @@ def run(quick: bool = False):
             mean, resid = compression.compressed_mean(gl[0], "data")
             return mean[None], resid[None]
 
-        cmean_fn = shard_map(
+        cmean_fn = jax.shard_map(
             f, mesh=mesh, in_specs=(P("data"),),
-            out_specs=(P("data"), P("data")), check_rep=False,
+            out_specs=(P("data"), P("data")), check_vma=False,
         )
 
         def cmean():

@@ -11,7 +11,10 @@ bench-smoke job uploads it as the ``BENCH_PR.json`` artifact), and
 (exit 1 on any shared row slower than 2.5x — the CI regression gate;
 the baseline refreshes from main pushes).
 Default is quick mode (small scale factors) so the whole suite runs in
-minutes on CPU; --full uses larger data.
+minutes on CPU; --full uses larger data.  A suite that raises prints a
+``<suite>/SUITE_ERROR`` row, the remaining suites still run, and the
+run exits 1.  JAX's persistent compilation cache is on
+(``repro.compile_cache``).
 """
 from __future__ import annotations
 
@@ -41,9 +44,12 @@ def main() -> None:
     quick = not args.full
     sf = args.sf or (0.01 if quick else 0.05)
 
+    from repro import compile_cache
+
+    compile_cache.enable()
+
     from . import (
         bench_compile,
-        bench_cores,
         bench_dist,
         bench_join,
         bench_loading,
@@ -73,11 +79,11 @@ def main() -> None:
         "spill": lambda: bench_spill.run(sf=sf, quick=quick),
         "loading": lambda: bench_loading.run(sf=sf, quick=quick),
         "memory": lambda: bench_memory.run(sf=sf, quick=quick),
-        "cores": lambda: bench_cores.run(sf=sf, quick=quick),
         "roofline": lambda: bench_roofline.run(quick=quick),
     }
     only = set(filter(None, args.only.split(",")))
     print("name,us_per_call,derived")
+    failed = []
     for name, fn in suites.items():
         if only and name not in only:
             continue
@@ -87,6 +93,7 @@ def main() -> None:
             fn()
         except Exception as e:  # noqa: BLE001
             print(f"{name}/SUITE_ERROR,0,{type(e).__name__}: {e}", flush=True)
+            failed.append(name)
         print(f"# {name} done in {time.time() - t0:.1f}s", flush=True)
     if args.json:
         from .common import write_json
@@ -97,6 +104,9 @@ def main() -> None:
 
         if not compare_baseline(args.compare):
             sys.exit(1)
+    if failed:
+        print(f"# suites raised: {','.join(failed)}", file=sys.stderr)
+        sys.exit(1)
 
 
 if __name__ == "__main__":

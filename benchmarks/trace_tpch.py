@@ -30,12 +30,13 @@ def main() -> None:
     )
     args = ap.parse_args()
 
-    from repro import obs, sql
+    from repro import compile_cache, obs, sql
     from repro.core.config import CONFIG
     from repro.queries.tpch_sql import sql_text
 
     from .common import tpch_frames
 
+    compile_cache.enable()
     frames = tpch_frames(args.sf)
     qnames = [q.strip() for q in args.queries.split(",") if q.strip()]
 

@@ -16,8 +16,10 @@ class EngineConfig:
     # dictionary-encoded into the int tensor; above it they are offloaded
     # (paper §III-c/d uses 50%).
     card_threshold: float = 0.5
-    # Measure tensor dtype. float64 on CPU hosts for exact analytics;
-    # a TPU deployment would flip this to float32 (see DESIGN.md §2).
+    # Measure tensor dtype: float64 on every backend, so answers match
+    # the references.  The TPU has no native float64 and emulates it
+    # with pairs of float32; narrowing the measures is an open decision
+    # (it changes results).
     float_dtype: str = "float64"
     # When True, string predicates on offloaded columns run on the packed
     # (n, maxlen) uint8 device tensor (Pallas kernel on TPU, jnp ref on

@@ -49,6 +49,7 @@ import jax.numpy as jnp
 
 from .config import CONFIG
 from . import encoding
+from .prims import bounds, compact
 
 INT = jnp.int64
 # Sentinel stored in int/code slots of null cells (the hidden validity
@@ -416,9 +417,7 @@ class TensorFrame:
         st = self.col_stats(name)
         if st is not None and st.vmin is not None:
             return int(st.vmin), int(st.vmax)
-        arr = self.col_values(name)
-        b = np.asarray(jnp.stack([arr.min(), arr.max()]))
-        lo, hi = int(b[0]), int(b[1])
+        lo, hi = bounds(self.col_values(name))
         self.set_stats(name, vmin=lo, vmax=hi)
         return lo, hi
 
@@ -817,8 +816,7 @@ class TensorFrame:
         count; the payload gather is deferred behind the view)."""
         mask = jnp.asarray(mask)
         count = int(mask.sum())
-        idx = jnp.nonzero(mask, size=count)[0].astype(INT)
-        return self.take(idx, stats="subset")
+        return self.take(compact(mask, count), stats="subset")
 
     def filter(self, expr) -> "TensorFrame":
         from .expr import Expr

@@ -22,6 +22,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from . import prims
 from .frame import INT, TensorFrame
 
 _SPLIT_K1 = np.uint64(0x9E3779B97F4A7C15)
@@ -61,14 +62,12 @@ def key_codes(frame: TensorFrame, name: str) -> Tuple[jax.Array, int]:
         codes, dictionary = frame.offloaded[name].codes()
         return codes, int(dictionary.shape[0])
     if m.kind == "float":
-        # group-by on measures (e.g. TPC-H Q10's c_acctbal): bitcast to
-        # integer lanes — bit equality == value equality for our data
+        # group-by on measures (e.g. TPC-H Q10's c_acctbal): a value's
+        # rank in the sorted column is its code
         f = frame.col_values(name)
-        arr = jax.lax.bitcast_convert_type(f, jnp.int64 if f.dtype == jnp.float64 else jnp.int32).astype(INT)
-        if arr.shape[0] == 0:
-            return arr, 1
-        uniq = jnp.unique(arr)
-        return jnp.searchsorted(uniq, arr).astype(INT), int(uniq.shape[0])
+        if f.shape[0] == 0:
+            return f.astype(INT), 1
+        return prims.float_rank(f), int(f.shape[0]) + 1
     arr = frame.col_values(name)
     if arr.shape[0] == 0:
         return arr, 1
@@ -77,8 +76,8 @@ def key_codes(frame: TensorFrame, name: str) -> Tuple[jax.Array, int]:
     if span <= max(4 * arr.shape[0], 1 << 20):
         return arr - lo, span
     # sparse domain: densify
-    uniq = jnp.unique(arr)
-    return jnp.searchsorted(uniq, arr).astype(INT), int(uniq.shape[0])
+    _, codes, m = distinct(arr, lohi=(lo, hi))
+    return codes, m
 
 
 def composite_key(
@@ -121,7 +120,7 @@ _JIT_MIN_ROWS = 1 << 17
 
 def _dense_rank(packed: jax.Array, domain: int):
     present = jnp.zeros((domain,), dtype=INT).at[packed].set(1)
-    ranks = jnp.cumsum(present) - 1  # value -> its sorted-unique id
+    ranks = prims.prefix_sum(present) - 1  # value -> its sorted-unique id
     return present, ranks
 
 
@@ -141,7 +140,9 @@ _dense_compact_jit = jax.jit(_dense_compact, static_argnums=(3,))
 
 
 def distinct(
-    packed: jax.Array, domain: Optional[int] = None
+    packed: jax.Array,
+    domain: Optional[int] = None,
+    lohi: Optional[Tuple[int, int]] = None,
 ) -> Tuple[jax.Array, jax.Array, int]:
     """(sorted_uniques, group_ids, n_groups). Host-syncs n_groups.
 
@@ -154,8 +155,8 @@ def distinct(
     Sort path: one argsort + run boundaries + two scatters: group ids
     are the run ranks scattered back through the sort permutation, and
     the uniques compact by rank — no second sort (``jnp.unique``) and
-    no binary search.  Either path syncs only the single deferred
-    n_groups fetch.
+    no binary search.  ``lohi`` bounds the keys when the caller knows
+    them; otherwise the argsort fetches them.
     """
     n = int(packed.shape[0])
     if n == 0:
@@ -170,12 +171,12 @@ def distinct(
             packed, present, ranks, m
         )
         return uniques, gids, m
-    order = jnp.argsort(packed)
+    order = prims.argsort(packed, lohi)
     sp = packed[order]
     boundary = jnp.concatenate(
         [jnp.ones((1,), dtype=bool), sp[1:] != sp[:-1]]
     )
-    ranks = jnp.cumsum(boundary.astype(INT)) - 1  # group id, sorted order
+    ranks = prims.prefix_sum(boundary.astype(INT)) - 1  # group id, sorted order
     m = int(ranks[-1]) + 1  # the one host sync
     uniques = jnp.zeros((m,), dtype=packed.dtype).at[ranks].set(sp)
     gids = jnp.zeros((n,), dtype=INT).at[order].set(ranks)

@@ -34,6 +34,7 @@ nulls -2, and both build and probe paths reject negatives.
 from __future__ import annotations
 
 import dataclasses
+from functools import partial
 from typing import Dict, List, Sequence, Tuple, Union
 
 import numpy as np
@@ -41,8 +42,9 @@ import jax
 import jax.numpy as jnp
 
 from repro import obs
-from . import encoding
+from . import encoding, prims
 from .config import CONFIG
+from .hashing import distinct
 from .frame import (
     INT,
     ColumnMeta,
@@ -134,12 +136,9 @@ def shared_key_codes(
 
 
 def _densify_pair(lp: jax.Array, rp: jax.Array) -> Tuple[jax.Array, jax.Array, int]:
-    uniq = jnp.unique(jnp.concatenate([lp, rp]))
-    return (
-        jnp.searchsorted(uniq, lp).astype(INT),
-        jnp.searchsorted(uniq, rp).astype(INT),
-        int(uniq.shape[0]),
-    )
+    nl = int(lp.shape[0])
+    _, codes, m = distinct(jnp.concatenate([lp, rp]))
+    return codes[:nl], codes[nl:], m
 
 
 def composite_join_codes(
@@ -196,7 +195,7 @@ def _dar_probe(probe: jax.Array, build: jax.Array, domain: int):
     )
     pos = tbl[probe_idx]
     matched = pos >= 0
-    slots = jnp.cumsum(matched.astype(INT))
+    slots = prims.prefix_sum(matched.astype(INT))
     return pos, matched, slots
 
 
@@ -248,33 +247,38 @@ def sorted_probe_rows(
     """Many-to-many probe: sort build side once, binary-search ranges,
     expand via CSR arithmetic.  Returns (counts, probe_rows, build_rows).
 
-    The expansion ranks each output row within its probe's match run
-    using the run-boundary formulation shared with the Pallas segmented
-    reduction (``kernels.segment_reduce.run_ranks_sorted``), replacing
-    two of the three repeats with gathers.
+    Output row ``j`` belongs to the probe row whose output run holds
+    it: each probe row with matches writes its number at its run's
+    first output slot, and a running max fills the run.  Its match is
+    then ``j`` minus the run's first slot, counted from the probe's
+    first match in the sorted build.
     """
-    from repro.kernels.segment_reduce import run_ranks_sorted
-
     npr = int(probe.shape[0])
-    order = jnp.argsort(build)
+    order = prims.argsort(build)
     sb = build[order]
     starts = jnp.searchsorted(sb, probe, side="left")
     ends = jnp.searchsorted(sb, probe, side="right")
     counts = (ends - starts).astype(INT)
     total = int(counts.sum())  # the one host sync of the probe
-    probe_rows = jnp.repeat(
-        jnp.arange(npr, dtype=INT), counts, total_repeat_length=total
-    )
-    within = run_ranks_sorted(probe_rows)
-    build_rows = order[starts.astype(INT)[probe_rows] + within]
-    return counts, probe_rows, build_rows
+    return (counts,) + _expand_runs(starts.astype(INT), counts, order, total)
+
+
+@partial(jax.jit, static_argnums=3)
+def _expand_runs(starts, counts, order, total: int):
+    first = prims.prefix_sum(counts) - counts  # first output slot per probe
+    marks = jnp.full((total,), -1, dtype=INT).at[
+        jnp.where(counts > 0, first, total)
+    ].max(jnp.arange(counts.shape[0], dtype=INT), mode="drop")
+    probe_rows = prims.running_max(marks)
+    within = jnp.arange(total, dtype=INT) - first[probe_rows]
+    return probe_rows, order[starts[probe_rows] + within]
 
 
 def sort_merge_join_rows(
     lcodes: jax.Array, rcodes: jax.Array
 ) -> Tuple[jax.Array, jax.Array]:
     """Fig. 12 baseline: sort-merge join (sorts BOTH sides)."""
-    lorder = jnp.argsort(lcodes)
+    lorder = prims.argsort(lcodes)
     ls = lcodes[lorder]
     _, li_sorted, ri = sorted_probe_rows(ls, rcodes)
     return lorder[li_sorted], ri
@@ -284,7 +288,12 @@ def membership(probe: jax.Array, build: jax.Array) -> jax.Array:
     """exists(probe value in build) — for semi/anti joins."""
     if int(build.shape[0]) == 0:
         return jnp.zeros(probe.shape, dtype=bool)
-    sb = jnp.sort(build)
+    return member_sorted(probe, prims.sort(build))
+
+
+def member_sorted(probe: jax.Array, sb: jax.Array) -> jax.Array:
+    """exists(probe value in the non-empty sorted build ``sb``);
+    negative probe keys (nulls) never match.  Traceable."""
     pos = jnp.clip(jnp.searchsorted(sb, probe), 0, sb.shape[0] - 1)
     return (sb[pos] == probe) & (probe >= 0)
 
@@ -556,7 +565,7 @@ def join(
                 # of the key combination — unless null keys collapsed
                 # codes, which would under-count)
                 STATS["uniqueness_sort_tests"] += 1
-                m_build = int((jnp.diff(jnp.sort(rcodes)) != 0).sum()) + 1
+                m_build = int((jnp.diff(prims.sort(rcodes)) != 0).sum()) + 1
                 unique_build = m_build == nb
                 if not null_keys:
                     right.set_stats(

@@ -39,9 +39,10 @@ import jax.numpy as jnp
 
 from repro.resilience import checkpoint
 
+from . import prims
 from .config import CONFIG
 from .frame import INT, TensorFrame
-from .join import _hstack, _right_name_map
+from .join import _hstack, _right_name_map, member_sorted
 
 #: Observable pipeline counters.  The spill keys mirror
 #: ``repro.store.spill.SPILL.counters`` (synced after every pipeline).
@@ -269,7 +270,7 @@ class HashBuild:
             return
         codes, kind, lo, hi, domain = got
         if self.how in ("semi", "anti"):
-            self._fast = ("member", kind, lo, hi, jnp.sort(codes), domain)
+            self._fast = ("member", kind, lo, hi, prims.sort(codes), domain)
             return
         # inner: need a provably-unique build side for direct addressing
         nb = self.build.nrows
@@ -278,7 +279,7 @@ class HashBuild:
         hint = self.build.unique_hint(self.build_keys)
         if hint is None:
             # pay the sort test ONCE at build time (not per chunk)
-            m_build = int((jnp.diff(jnp.sort(codes)) != 0).sum()) + 1
+            m_build = int((jnp.diff(prims.sort(codes)) != 0).sum()) + 1
             hint = m_build == nb
             self.build.set_stats(
                 self.build_keys[0], unique=bool(hint), distinct=m_build
@@ -342,10 +343,7 @@ class HashBuild:
                     if int(sb.shape[0]) == 0:
                         exists = jnp.zeros(codes.shape, dtype=bool)
                     else:
-                        pos = jnp.clip(
-                            jnp.searchsorted(sb, codes), 0, sb.shape[0] - 1
-                        )
-                        exists = (sb[pos] == codes) & (codes >= 0)
+                        exists = member_sorted(codes, sb)
                     return f.mask_rows(
                         exists if self.how == "semi" else ~exists
                     )
@@ -358,7 +356,7 @@ class HashBuild:
                 pos = payload[probe_idx]
                 matched = pos >= 0
                 cnt = int(matched.sum())  # the one sync per chunk
-                lrows = jnp.nonzero(matched, size=cnt)[0].astype(INT)
+                lrows = prims.compact(matched, cnt)
                 rrows = pos[lrows]
                 name_map = _right_name_map(
                     f, self.build, self._drop_right(), self.suffix

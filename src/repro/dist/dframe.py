@@ -23,7 +23,12 @@ scale out the way partitioned dataframe engines do:
 Every op takes *global* arrays plus a mesh and works unchanged on a
 1-device mesh (single-device fallback).  Inputs whose length does not
 divide the mesh size are padded with null keys (negative), which every
-op already ignores.
+op already ignores.  ``data_mesh`` builds its axis as ``Auto``: the
+outputs carry no mesh axis in their type, so the engine's single-device
+code (``mask_rows`` compacting a semi-join mask) consumes them as is.
+
+``dist_groupby_sum`` and ``dist_semi_join_mask`` count their calls in
+the ``repro.obs`` counters ``dist.groupby_sum`` and ``dist.semi_join``.
 """
 from __future__ import annotations
 
@@ -32,12 +37,13 @@ from typing import Tuple
 import numpy as np
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
+from jax.sharding import AxisType
 from jax.sharding import PartitionSpec as P
 
-from repro.core import hashing
+from repro import obs
+from repro.core import hashing, prims
 from repro.core.config import CONFIG
-from repro.core.join import membership
+from repro.core.join import member_sorted
 
 AXIS = "data"
 
@@ -48,7 +54,7 @@ AXIS = "data"
 def data_mesh(ndev: int | None = None):
     """A 1-D mesh over (the first ``ndev``) visible devices."""
     n = jax.device_count() if ndev is None else ndev
-    return jax.make_mesh((n,), (AXIS,))
+    return jax.make_mesh((n,), (AXIS,), axis_types=(AxisType.Auto,))
 
 
 def dist_enabled(nrows: int) -> bool:
@@ -109,10 +115,10 @@ def local_dense_sum(
     safe = jnp.where(ok, keys, domain).astype(jnp.int32)
     vz = jnp.where(ok, vals, jnp.zeros((), vals.dtype))
     if use_pallas:
-        from repro.kernels.segment_reduce import segment_sum_sorted_pallas
+        from repro.kernels import ops
 
-        order = jnp.argsort(safe)
-        return segment_sum_sorted_pallas(vz[order], safe[order], domain + 1)[:domain]
+        safe, vz = jax.lax.sort((safe, vz), num_keys=1, is_stable=False)
+        return ops.segment_sum_sorted(vz, safe, domain + 1)[:domain]
     out = jnp.zeros((domain + 1,), vz.dtype).at[safe].add(vz)
     return out[:domain]
 
@@ -134,17 +140,18 @@ def dist_groupby_sum(
     ``keys`` are dense group ids in ``[0, domain)`` (negative = null);
     returns the replicated ``(domain,)`` per-group sums.
     """
+    obs.metrics.counter("dist.groupby_sum").inc()
     keys, vals, _ = _pad_to(mesh, axis, keys, vals)
 
     def shard(k, v):
         return jax.lax.psum(local_dense_sum(k, v, domain, use_pallas=use_pallas), axis)
 
-    fn = shard_map(
+    fn = jax.shard_map(
         shard,
         mesh=mesh,
         in_specs=(P(axis), P(axis)),
         out_specs=P(),
-        check_rep=False,
+        check_vma=False,
     )
     return fn(keys, vals)
 
@@ -157,23 +164,23 @@ def dist_semi_join_mask(
 ) -> jax.Array:
     """``exists(probe[i] in build)`` with the probe side sharded.
 
-    The build side is broadcast to every shard (replicated in-spec) and
-    probed with the engine's sorted-membership kernel; negative probe
-    keys (nulls) never match, matching SQL semi/anti-join semantics.
+    The build side is sorted once, broadcast to every shard (replicated
+    in-spec) and probed with the engine's sorted-membership kernel;
+    negative probe keys (nulls) never match, matching SQL semi/anti-join
+    semantics.
     """
+    obs.metrics.counter("dist.semi_join").inc()
+    if int(build.shape[0]) == 0:
+        return jnp.zeros(probe.shape, dtype=bool)
     probe, _, n = _pad_to(mesh, axis, probe, None)
-
-    def shard(p, b):
-        return membership(p, b)
-
-    fn = shard_map(
-        shard,
+    fn = jax.shard_map(
+        member_sorted,
         mesh=mesh,
         in_specs=(P(axis), P()),
         out_specs=P(axis),
-        check_rep=False,
+        check_vma=False,
     )
-    return fn(probe, build)[:n]
+    return fn(probe, prims.sort(build))[:n]
 
 
 # ----------------------------------------------------------------------
@@ -248,11 +255,11 @@ def dist_repartition_by_key(
             jax.lax.psum(dropped_local, axis),
         )
 
-    fn = shard_map(
+    fn = jax.shard_map(
         shard,
         mesh=mesh,
         in_specs=(P(axis), P(axis)),
         out_specs=(P(axis), P(axis), P(axis), P()),
-        check_rep=False,
+        check_vma=False,
     )
     return fn(keys, vals)

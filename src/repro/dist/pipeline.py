@@ -20,7 +20,6 @@ from typing import Callable
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 AXIS = "pipe"
@@ -73,11 +72,11 @@ def pipeline_forward(
             jnp.where(sidx == stages - 1, outs, jnp.zeros((), outs.dtype)), axis
         )
 
-    fn = shard_map(
+    fn = jax.shard_map(
         shard,
         mesh=mesh,
         in_specs=(P(axis), P()),
         out_specs=P(),
-        check_rep=False,
+        check_vma=False,
     )
     return fn(weights, x)

@@ -35,7 +35,7 @@ def _kernel(cols_ref, out_ref, *, k: int):
     out_ref[...] = jnp.stack(lanes, axis=1)
 
 
-def hash32x2_pallas(cols: jax.Array, *, block_rows: int = _BN, interpret: bool = True) -> jax.Array:
+def hash32x2_pallas(cols: jax.Array, *, block_rows: int = _BN, interpret: bool) -> jax.Array:
     n, k = cols.shape
     pad = (-n) % block_rows
     if pad:

@@ -1,11 +1,11 @@
 """Public jit'd entry points for the Pallas kernels.
 
-Dispatch policy: on TPU the compiled Pallas kernel runs natively; on
-any other backend (this container is CPU) the kernel body executes in
-``interpret=True`` mode, and callers that need raw speed on CPU use the
-pure-XLA reference path (``ref.py``) — which is also what the multi-pod
-dry-run lowers, since Pallas TPU kernels cannot lower on the CPU
-backend (DESIGN.md §5).
+Dispatch policy: on TPU the Pallas kernel compiles natively (Mosaic);
+on any other backend the kernel body executes in ``interpret=True``
+mode, which is how the test suite runs them on CPU.  The kernels take
+``interpret`` as a required keyword, so a caller that bypasses this
+module has to choose.  ``ref.py`` holds the pure-XLA references the
+tests compare against.
 """
 from __future__ import annotations
 

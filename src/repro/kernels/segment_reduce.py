@@ -1,36 +1,47 @@
 """Pallas TPU kernel: segmented sum over *sorted* segment ids.
 
 The group-by aggregation hot spot (Alg. 2 step 4).  TPUs have no fast
-vector scatter, so the per-block reduction is reformulated as an MXU
-matmul: within a row block the (sorted) ids are *ranked* by run
-boundaries (rank = cumsum of id-changes, always < BN regardless of id
-gaps), a (BN, BN) one-hot over ranks reduces the block to per-run
-partials with one ``values @ one_hot`` — systolic-array friendly.  The
-(tiny) cross-block combine is a scatter-add done by the XLA wrapper.
+vector scatter, so the per-block reduction is reformulated for the
+vector and matrix units: within a block of BN rows the (sorted) ids are
+*ranked* by run boundaries (rank = number of id changes so far, always
+< BN regardless of id gaps), computed as one MXU product of the boundary
+flags with a triangular (BN, BN) matrix; a (BN slots, BN rows) one-hot
+over ranks then reduces the block to per-run partials and each run's
+segment id with lane reductions.  The (tiny) cross-block combine is a
+scatter-add done by the XLA wrapper.
+
+Layout: the inputs travel as one (1, n) row, blocked (1, BN) — lane
+dense, and legal under the (8, 128) tiling because the row dimension is
+the whole array; the per-slot outputs come back as (n, 1) columns
+blocked (BN, 1).
 """
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 _BN = 512
 
 
 def _kernel(vals_ref, ids_ref, partial_ref, slotid_ref):
-    vals = vals_ref[...].astype(jnp.float32)  # (BN,)
-    ids = ids_ref[...].astype(jnp.int32)  # (BN,) sorted ascending
-    bn = vals.shape[0]
-    prev = jnp.concatenate([ids[:1], ids[:-1]])
-    rank = jnp.cumsum((ids != prev).astype(jnp.int32))  # (BN,) in [0, BN)
-    iota = jax.lax.broadcasted_iota(jnp.int32, (bn, bn), 1)
-    onehot = (rank[:, None] == iota).astype(jnp.float32)  # (BN rows, BN slots)
-    partial_ref[...] = vals[None, :] @ onehot  # (1, BN) per-slot sums
-    # segment id owning each slot (integer max over the slot's rows;
-    # empty slots get 0 and carry a zero partial)
-    slotid_ref[...] = jnp.max(
-        (rank[:, None] == iota).astype(jnp.int32) * ids[:, None], axis=0
-    )[None, :]
+    vals = vals_ref[...].astype(jnp.float32)  # (1, BN)
+    ids = ids_ref[...]  # (1, BN) int32, sorted ascending
+    bn = vals.shape[1]
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, bn), 1)
+    prev = pltpu.roll(ids, 1, 1)  # prev[i] = ids[i - 1] (wraps at 0)
+    boundary = ((ids != prev) & (lane > 0)).astype(jnp.float32)
+    row = jax.lax.broadcasted_iota(jnp.int32, (bn, bn), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (bn, bn), 1)
+    upper = (row <= col).astype(jnp.float32)
+    # rank[i] = sum_{j <= i} boundary[j]; 0/1 terms, so exact in f32
+    rank = jnp.dot(boundary, upper, preferred_element_type=jnp.float32)
+    onehot = rank.astype(jnp.int32) == row  # (BN slots, BN rows)
+    partial_ref[...] = jnp.sum(jnp.where(onehot, vals, 0.0), axis=1, keepdims=True)
+    # segment id owning each slot (ids are >= 0; empty slots get 0 and
+    # carry a zero partial)
+    slotid_ref[...] = jnp.max(jnp.where(onehot, ids, 0), axis=1, keepdims=True)
 
 
 def segment_sum_sorted_pallas(
@@ -39,9 +50,11 @@ def segment_sum_sorted_pallas(
     num_segments: int,
     *,
     block_rows: int = _BN,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jax.Array:
-    """values (n,), seg_ids (n,) sorted ascending, ids >= 0."""
+    """values (n,), seg_ids (n,) sorted ascending, ids >= 0.
+
+    ``block_rows`` is a multiple of 128 (one lane-dense block)."""
     n = values.shape[0]
     if n == 0:
         return jnp.zeros((num_segments,), dtype=jnp.float32)
@@ -51,46 +64,27 @@ def segment_sum_sorted_pallas(
         # pad with the last id so padding lands in an existing bucket
         # with zero value contribution
         seg_ids = jnp.pad(seg_ids, (0, pad), mode="edge")
-    nblocks = values.shape[0] // block_rows
+    total = values.shape[0]
     partials, slotids = pl.pallas_call(
         _kernel,
         out_shape=(
-            jax.ShapeDtypeStruct((nblocks, block_rows), jnp.float32),
-            jax.ShapeDtypeStruct((nblocks, block_rows), jnp.int32),
+            jax.ShapeDtypeStruct((total, 1), jnp.float32),
+            jax.ShapeDtypeStruct((total, 1), jnp.int32),
         ),
-        grid=(nblocks,),
+        grid=(total // block_rows,),
         in_specs=[
-            pl.BlockSpec((block_rows,), lambda i: (i,)),
-            pl.BlockSpec((block_rows,), lambda i: (i,)),
+            pl.BlockSpec((1, block_rows), lambda i: (0, i)),
+            pl.BlockSpec((1, block_rows), lambda i: (0, i)),
         ],
         out_specs=(
-            pl.BlockSpec((1, block_rows), lambda i: (i, 0)),
-            pl.BlockSpec((1, block_rows), lambda i: (i, 0)),
+            pl.BlockSpec((block_rows, 1), lambda i: (i, 0)),
+            pl.BlockSpec((block_rows, 1), lambda i: (i, 0)),
         ),
         interpret=interpret,
-    )(values.astype(jnp.float32), seg_ids.astype(jnp.int32))
-    out = jnp.zeros((num_segments,), dtype=jnp.float32)
-    out = out.at[slotids.reshape(-1)].add(partials.reshape(-1))
-    return out
-
-
-def run_ranks_sorted(ids: jax.Array) -> jax.Array:
-    """Within-run rank (0-based) of each element of a *sorted* id vector.
-
-    The same run-boundary formulation the kernel above uses to rank ids
-    inside a block (``rank = cumsum(id-changes)``), turned inside out:
-    instead of the run index we want each element's offset *within* its
-    run, which is ``position - run_start`` with run starts recovered by
-    a cumulative max over boundary positions.  Fully on-device — no
-    host sync — which is what the join's sorted-probe CSR expansion
-    needs (it runs behind a single deferred total-count fetch).
-    """
-    n = ids.shape[0]
-    if n == 0:
-        return jnp.zeros((0,), dtype=jnp.int64)
-    idx = jnp.arange(n, dtype=jnp.int64)
-    boundary = jnp.concatenate(
-        [jnp.zeros((1,), dtype=bool), ids[1:] != ids[:-1]]
+    )(
+        values.astype(jnp.float32).reshape(1, total),
+        seg_ids.astype(jnp.int32).reshape(1, total),
     )
-    starts = jax.lax.cummax(jnp.where(boundary, idx, 0))
-    return idx - starts
+    out = jnp.zeros((num_segments,), dtype=jnp.float32)
+    return out.at[slotids.reshape(-1)].add(partials.reshape(-1))
+

@@ -6,6 +6,10 @@ This is the compute core of the paper's headline TPC-H Q13 UDF
 sliding-window byte comparisons over VMEM tiles — one row block per
 grid step, the m pattern bytes unrolled statically so the VPU sees pure
 vector compares/ands.
+
+Layout: the per-row vectors (lengths, start offsets, the result) travel
+as (n, 1) columns blocked (BN, 1), which fit the TPU's (8, 128) tiling;
+1-D blocks do not.
 """
 from __future__ import annotations
 
@@ -24,17 +28,17 @@ def _make_kernel(pattern_bytes: tuple, L: int, use_start: bool):
 
     def kernel(packed_ref, lens_ref, start_ref, out_ref):
         b = packed_ref[...].astype(jnp.int32)  # (BN, L)
-        lens = lens_ref[...].astype(jnp.int32)
+        lens = lens_ref[...].astype(jnp.int32)  # (BN, 1)
         npos = L - m + 1
         match = jnp.ones((b.shape[0], npos), dtype=jnp.bool_)
         for j in range(m):
             match = match & (b[:, j : j + npos] == jnp.int32(pattern_bytes[j]))
         pos = jax.lax.broadcasted_iota(jnp.int32, (b.shape[0], npos), 1)
-        ok = match & (pos + m <= lens[:, None])
+        ok = match & (pos + m <= lens)
         if use_start:
-            ok = ok & (pos >= start_ref[...].astype(jnp.int32)[:, None])
+            ok = ok & (pos >= start_ref[...].astype(jnp.int32))
         scores = jnp.where(ok, pos, jnp.int32(npos + 1))
-        first = scores.min(axis=1)
+        first = scores.min(axis=1, keepdims=True)
         out_ref[...] = jnp.where(first <= npos, first, jnp.int32(-1))
 
     return kernel
@@ -47,7 +51,7 @@ def substr_find_pallas(
     start: Optional[jax.Array] = None,
     *,
     block_rows: int = _BN,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jax.Array:
     n, L = packed.shape
     m = int(pattern.shape[0])
@@ -64,20 +68,17 @@ def substr_find_pallas(
         packed = jnp.pad(packed, ((0, pad), (0, 0)))
         lens = jnp.pad(lens, (0, pad))
         start = jnp.pad(start, (0, pad))
-    grid = (packed.shape[0] // block_rows,)
+    total = packed.shape[0]
+    column = pl.BlockSpec((block_rows, 1), lambda i: (i, 0))
     out = pl.pallas_call(
         _make_kernel(pat, L, use_start),
-        out_shape=jax.ShapeDtypeStruct((packed.shape[0],), jnp.int32),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((block_rows, L), lambda i: (i, 0)),
-            pl.BlockSpec((block_rows,), lambda i: (i,)),
-            pl.BlockSpec((block_rows,), lambda i: (i,)),
-        ],
-        out_specs=pl.BlockSpec((block_rows,), lambda i: (i,)),
+        out_shape=jax.ShapeDtypeStruct((total, 1), jnp.int32),
+        grid=(total // block_rows,),
+        in_specs=[pl.BlockSpec((block_rows, L), lambda i: (i, 0)), column, column],
+        out_specs=column,
         interpret=interpret,
-    )(packed, lens, start)
-    return out[:n]
+    )(packed, lens.reshape(total, 1), start.reshape(total, 1))
+    return out[:n, 0]
 
 
 def exists_before_pallas(packed, lens, pat_a, pat_b, **kw) -> jax.Array:

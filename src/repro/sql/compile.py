@@ -47,6 +47,7 @@ import jax
 import jax.numpy as jnp
 
 from repro import obs
+from repro.core import prims
 from repro.core.config import CONFIG
 from repro.core.expr import Expr, Value
 from repro.core.frame import (
@@ -93,6 +94,7 @@ __all__ = [
     "Unsupported",
     "clear_cache",
     "maybe_execute_compiled",
+    "negative_plans",
     "reset_stats",
 ]
 
@@ -116,6 +118,7 @@ def _fresh_stats() -> Dict:
         "evictions": 0,  # LRU capacity evictions
         "compiles": 0,  # successful trace+compile
         "fallbacks": 0,  # unsupported plan -> op-by-op engine
+        "fallback_reasons": {},  # reason -> fallback count
         "compile_failures": 0,  # unexpected trace/compile crashes
         "skipped_small": 0,  # auto mode: input under compiled_min_rows
         "plans": {},  # digest -> per-plan timing/shape record
@@ -147,7 +150,28 @@ def _stats_snapshot() -> Dict:
     with _LOCK:
         out = {k: v for k, v in STATS.items() if k != "plans"}
         out["plans"] = {d: dict(r) for d, r in STATS["plans"].items()}
+        out["fallback_reasons"] = dict(STATS["fallback_reasons"])
     return out
+
+
+def negative_plans() -> Dict[str, str]:
+    """``{plan digest: reason}`` of every plan negative-cached to op-by-op
+    dispatch: the plans the compiled path refused, and why."""
+    with _LOCK:
+        return {_digest(f): r for f, r in _NEGATIVE.items()}
+
+
+def _digest(fpr: str) -> str:
+    return hashlib.sha1(fpr.encode()).hexdigest()[:12]
+
+
+def _fallback(reason: str) -> None:
+    """Count one dispatch to the op-by-op engine under ``reason``
+    (``_LOCK`` is re-entrant, so callers may hold it)."""
+    with _LOCK:
+        STATS["fallbacks"] += 1
+        reasons = STATS["fallback_reasons"]
+        reasons[reason] = reasons.get(reason, 0) + 1
 
 
 obs.metrics.register_group("sql.compile", _stats_snapshot, reset_stats)
@@ -323,9 +347,10 @@ class _PrepTable:
     def __init__(self, frame: TensorFrame):
         self.frame = frame
         self.cap = _pow2(frame.nrows)
-        # cached padded (itensor, ftensor, n) args — only used when the
-        # backend ignores donation (CPU), where reuse is safe
-        self.pads = None
+        # (int slots, float slots) -> cached padded (itensor, ftensor,
+        # n) args — only used when the backend ignores donation (CPU),
+        # where reuse is safe
+        self.pads: Dict[Tuple[Tuple[int, ...], Tuple[int, ...]], Tuple] = {}
         # tuple(sorted cols) -> bool uniqueness verdict (host-computed
         # once; part of the fingerprint since it drives join strategy)
         self.combos: Dict[Tuple[str, ...], bool] = {}
@@ -410,12 +435,33 @@ def _ensure_unique_locked(prep: _PrepTable, cols: Tuple[str, ...]) -> bool:
     return verdict
 
 
-def _table_sig(name: str, prep: _PrepTable) -> str:
-    f = prep.frame
+class _Narrow:
+    """The columns a plan reads from one base table, renumbered into the
+    narrow tensors ``_build_args`` copies (only these slots are padded
+    and passed: on the TPU every copied column costs HBM and time)."""
+
+    __slots__ = ("columns", "islots", "fslots")
+
+    def __init__(self, f: TensorFrame, names) -> None:
+        self.columns: Dict[str, ColumnMeta] = {}
+        islots: List[int] = []
+        fslots: List[int] = []
+        hidden = _valid_name("")
+        for name, m in f.columns.items():
+            base = name[len(hidden):] if name.startswith(hidden) else name
+            if base not in names:
+                continue
+            slots = fslots if m.kind == "float" else islots
+            self.columns[name] = dataclasses.replace(m, slot=len(slots), block=0)
+            slots.append(m.slot)
+        self.islots, self.fslots = tuple(islots), tuple(fslots)
+
+
+def _table_sig(name: str, prep: _PrepTable, narrow: _Narrow) -> str:
     cols = ",".join(
         f"{n}:{m.kind}:{m.slot}:"
         f"{0 if m.dictionary is None else id(m.dictionary)}"
-        for n, m in f.columns.items()
+        for n, m in narrow.columns.items()
     )
     combos = ";".join(
         f"{'+'.join(k)}={int(v)}" for k, v in sorted(prep.combos.items())
@@ -424,8 +470,8 @@ def _table_sig(name: str, prep: _PrepTable) -> str:
         f"{n}={lo}:{hi}" for n, (lo, hi) in sorted(prep.bounds.items())
     )
     return (
-        f"{name}[cap={prep.cap},iw={f.itensor.shape[1]},"
-        f"fw={f.ftensor.shape[1]}]({cols})u({combos})b({bounds})"
+        f"{name}[cap={prep.cap},iw={len(narrow.islots)},"
+        f"fw={len(narrow.fslots)}]({cols})u({combos})b({bounds})"
     )
 
 
@@ -549,7 +595,7 @@ def _compact(ct: CTable) -> CTable:
     """Gather the live rows into [0, n) (no-op when already there)."""
     if ct.mask is None:
         return ct
-    idx = jnp.nonzero(ct.mask, size=ct.cap, fill_value=0)[0]
+    idx = prims.compact(ct.mask, ct.cap)
     return _gather_rows(ct, idx, ct.n)
 
 
@@ -558,10 +604,23 @@ def _is_unique(ct: CTable, keys) -> bool:
     return any(u <= ks for u in ct.unique)
 
 
+def _rows(t, idx):
+    """``t[idx]`` for an (n, k) payload, gathered column-major: on the TPU
+    a row-major (n, k) temporary pads k up to 128 lanes (a 4 GB copy at
+    n = 2^23 for any k), a (k, n) one only pads k up to 8 sublanes."""
+    return t.T[:, idx].T
+
+
+def _hcat(a, b):
+    """Column-major ``concatenate([a, b], axis=1)`` (see ``_rows``)."""
+    return jnp.concatenate([a.T, b.T], axis=0).T
+
+
 def _gather_rows(ct: CTable, idx, n, unique=None) -> CTable:
     f = ct.frame
     out = TensorFrame(
-        f.itensor[idx], f.ftensor[idx], dict(f.columns), {}, int(idx.shape[0])
+        _rows(f.itensor, idx), _rows(f.ftensor, idx), dict(f.columns), {},
+        int(idx.shape[0]),
     )
     # row subsets keep value bounds (padding rows are masked everywhere)
     return CTable(
@@ -593,13 +652,20 @@ def _masked_max(v, ok):
     return jnp.max(jnp.where(ok, v, -_BIG))
 
 
+def _sort(v):
+    """Ascending one-operand sort (stability means nothing for one
+    operand, and the stable form compiles three times slower on TPU)."""
+    return jax.lax.sort(v, is_stable=False)
+
+
 def _rank(v, n: int):
     """Equality-preserving codes in ``[0, n]``: each value's first
-    position in its own sorted order.  sort+searchsorted is several
-    times cheaper than argsort/lexsort on the CPU XLA backend, which is
-    why every operator here range-compresses through this instead of
-    sorting composite keys directly."""
-    return jnp.searchsorted(jnp.sort(v), v)
+    position in its own sorted order.  A one-operand sort plus a
+    binary search is several times cheaper than argsort/lexsort, to run
+    on the CPU and to compile for the TPU, which is why every operator
+    here range-compresses through this instead of sorting composite
+    keys directly."""
+    return jnp.searchsorted(_sort(v), v)
 
 
 def _expr_bounds(ct: CTable, e) -> Optional[Tuple[int, int]]:
@@ -779,12 +845,12 @@ def _stack_sides(lf: TensorFrame, l_idx, rf: TensorFrame, r_idx, cap: int):
     keeps that side's rows in place — no gather at all."""
     if set(lf.columns) & set(rf.columns):
         raise Unsupported("join sides share column names")
-    lit_ = lf.itensor if l_idx is None else lf.itensor[l_idx]
-    lft_ = lf.ftensor if l_idx is None else lf.ftensor[l_idx]
-    rit_ = rf.itensor if r_idx is None else rf.itensor[r_idx]
-    rft_ = rf.ftensor if r_idx is None else rf.ftensor[r_idx]
-    it = jnp.concatenate([lit_, rit_], axis=1)
-    ft = jnp.concatenate([lft_, rft_], axis=1)
+    lit_ = lf.itensor if l_idx is None else _rows(lf.itensor, l_idx)
+    lft_ = lf.ftensor if l_idx is None else _rows(lf.ftensor, l_idx)
+    rit_ = rf.itensor if r_idx is None else _rows(rf.itensor, r_idx)
+    rft_ = rf.ftensor if r_idx is None else _rows(rf.ftensor, r_idx)
+    it = _hcat(lit_, rit_)
+    ft = _hcat(lft_, rft_)
     iw, fw = lf.itensor.shape[1], lf.ftensor.shape[1]
     cols: Dict[str, ColumnMeta] = {}
     for name, m in lf.columns.items():
@@ -805,7 +871,7 @@ def _probe_build(build: CTable, bcode, bok, pcode, pok, S):
         matched = (brow >= 0) & pok
         return matched, jnp.clip(brow, 0, build.cap - 1)
     key = jnp.where(bok, bcode, _BIG)
-    s = jnp.sort(key)
+    s = _sort(key)
     pos = jnp.searchsorted(s, pcode)
     posc = jnp.clip(pos, 0, build.cap - 1)
     matched = (pos < build.cap) & (s[posc] == pcode) & pok
@@ -831,7 +897,7 @@ def _c_join(node: Join, lct: CTable, rct: CTable) -> CTable:
             tbl = _dense_lookup(rcode, rok, rct.cap, S)
             present = (tbl[jnp.clip(lcode, 0, S - 1)] >= 0) & lok
         else:
-            s = jnp.sort(jnp.where(rok, rcode, _BIG))
+            s = _sort(jnp.where(rok, rcode, _BIG))
             pos = jnp.searchsorted(s, lcode)
             posc = jnp.clip(pos, 0, rct.cap - 1)
             present = (pos < rct.cap) & (s[posc] == lcode) & lok
@@ -924,7 +990,7 @@ def _c_left_expand(node, lct, lcode, lok, rct, rcode, rok, S) -> CTable:
     matched_r, lrow_r = _probe_build(lct, lcode, lok, rcode, rok, S)
 
     # matched pairs, compacted over the right capacity
-    idx_r = jnp.nonzero(matched_r, size=rct.cap, fill_value=0)[0]
+    idx_r = prims.compact(matched_r, rct.cap)
     n1 = jnp.sum(matched_r, dtype=INT)
     # left rows never claimed (null-key left rows stay too: engine left
     # joins keep them with NULL right payloads)
@@ -934,7 +1000,7 @@ def _c_left_expand(node, lct, lcode, lok, rct, rcode, rok, S) -> CTable:
         .max(matched_r.astype(INT))
     )
     keep_l = lct.row_valid & (hit == 0)
-    idx_l = jnp.nonzero(keep_l, size=lct.cap, fill_value=0)[0]
+    idx_l = prims.compact(keep_l, lct.cap)
     n2 = jnp.sum(keep_l, dtype=INT)
 
     live = jnp.concatenate(
@@ -944,7 +1010,7 @@ def _c_left_expand(node, lct, lcode, lok, rct, rcode, rok, S) -> CTable:
         ]
     )
     # compact both parts into contiguous [0, n1+n2)
-    sel = jnp.nonzero(live, size=rct.cap + lct.cap, fill_value=0)[0]
+    sel = prims.compact(live, rct.cap + lct.cap)
     l_all = jnp.concatenate([lrow_r[idx_r], idx_l])[sel]
     r_all = jnp.concatenate([idx_r, jnp.zeros((lct.cap,), dtype=INT)])[sel]
     matched_all = jnp.concatenate(
@@ -1027,18 +1093,18 @@ def _group_ids(code, S: int, rv, cap: int, dmax: Optional[int] = None):
             jnp.ones((cap,), dtype=INT), ids, num_segments=S
         )
         present = cnt > 0
-        dense = jnp.cumsum(present.astype(INT)) - 1
+        dense = prims.prefix_sum(present.astype(INT)) - 1
         n_groups = jnp.sum(present, dtype=INT)
         gids = jnp.where(rv, dense[jnp.clip(code, 0, S - 1)], cap_out)
         return gids, n_groups, cap_out
     scode = jnp.where(rv, code, _BIG)
-    s = jnp.sort(scode)
+    s = _sort(scode)
     live = s < _BIG
     first = jnp.concatenate([live[:1], (s[1:] != s[:-1]) & live[1:]])
     n_groups = jnp.sum(first, dtype=INT)
     # dense id per sorted slot; a live row reads it back through the
     # first-occurrence slot of its own code
-    did = jnp.cumsum(first.astype(INT)) - 1
+    did = prims.prefix_sum(first.astype(INT)) - 1
     gids = jnp.where(rv, did[jnp.searchsorted(s, scode)], cap_out)
     return gids, n_groups, cap_out
 
@@ -1169,7 +1235,7 @@ def _seg_nunique(v, ok, gids, cap_out: int, cap: int):
     twin of agg._segment_nunique, minus the host sync and the lexsort."""
     M = 2 * cap  # rank(v) <= cap < M, so the packing is collision-free
     pair = gids * M + _rank(v, cap)
-    s = jnp.sort(jnp.where(ok, pair, _BIG))
+    s = _sort(jnp.where(ok, pair, _BIG))
     live = s < _BIG
     first = jnp.concatenate([live[:1], (s[1:] != s[:-1]) & live[1:]])
     seg = jnp.where(live, s // M, cap_out)
@@ -1311,7 +1377,7 @@ def _c_distinct(ct: CTable) -> CTable:
         gids,
         num_segments=cap_out,
     )
-    idx = jnp.clip(jnp.sort(rep), 0, ct.cap - 1)
+    idx = jnp.clip(_sort(rep), 0, ct.cap - 1)
     return _gather_rows(
         ct, idx, n_out, unique=ct.unique | {frozenset(names)}
     )
@@ -1434,9 +1500,10 @@ def _finalize(ct: CTable) -> CTable:
 # trace context + compiled-program construction
 # ----------------------------------------------------------------------
 class _Ctx:
-    def __init__(self, bases, preps, params_i, params_f, slots):
+    def __init__(self, bases, preps, narrow, params_i, params_f, slots):
         self.bases = bases  # table -> (itensor, ftensor, n) traced
         self.preps = preps
+        self.narrow = narrow
         self.params_i = params_i
         self.params_f = params_f
         self.slots = slots  # global param index -> ('i'|'f', position)
@@ -1446,12 +1513,11 @@ class _Ctx:
         got = self._base_memo.get(name)
         if got is None:
             it, ft, n = self.bases[name]
-            prep = self.preps[name]
             cols = {
                 k: dataclasses.replace(m)
-                for k, m in prep.frame.columns.items()
+                for k, m in self.narrow[name].columns.items()
             }
-            got = CTable(TensorFrame(it, ft, cols, {}, prep.cap), n)
+            got = CTable(TensorFrame(it, ft, cols, {}, self.preps[name].cap), n)
             self._base_memo[name] = got
         return got
 
@@ -1510,22 +1576,22 @@ def _donating() -> bool:
     return jax.default_backend() != "cpu"
 
 
-def _build_args(preps, order, values, slots, n_i, n_f):
+def _build_args(preps, narrow, order, values, slots, n_i, n_f):
     args = []
     fresh = _donating()
     for name in order:
-        prep = preps[name]
+        prep, nw = preps[name], narrow[name]
         f = prep.frame
-        if fresh or prep.pads is None:
+        key = (nw.islots, nw.fslots)
+        pads = None if fresh else prep.pads.get(key)
+        if pads is None:
             pads = (
-                _pad_rows(f.itensor, prep.cap),
-                _pad_rows(f.ftensor, prep.cap),
+                _pad_rows(f.itensor, nw.islots, prep.cap),
+                _pad_rows(f.ftensor, nw.fslots, prep.cap),
                 jnp.asarray(f.nrows, dtype=INT),
             )
             if not fresh:
-                prep.pads = pads
-        else:
-            pads = prep.pads
+                prep.pads[key] = pads
         args.extend(pads)
     vi = np.zeros((n_i,), dtype=np.int64)
     vf = np.zeros((n_f,), dtype=np.float64)
@@ -1539,16 +1605,18 @@ def _build_args(preps, order, values, slots, n_i, n_f):
     return args
 
 
-def _pad_rows(t, cap: int):
+def _pad_rows(t, slots: Tuple[int, ...], cap: int):
     # always a FRESH buffer (never the base tensor itself): the padded
     # inputs are donated to the executable, and donating a shared
     # buffer would invalidate the caller's base table
     n = t.shape[0]
-    out = jnp.zeros((cap, t.shape[1]), dtype=t.dtype)
-    return out.at[:n].set(t)
+    out = jnp.zeros((cap, len(slots)), dtype=t.dtype)
+    if not slots:
+        return out
+    return out.at[:n].set(t[:, jnp.asarray(slots, dtype=INT)])
 
 
-def _compile_entry(fpr, pplan, preps, order, kinds, args):
+def _compile_entry(fpr, pplan, preps, narrow, order, kinds, args):
     from repro.resilience.faults import fault_point
 
     fault_point("compile")
@@ -1561,7 +1629,7 @@ def _compile_entry(fpr, pplan, preps, order, kinds, args):
         for name in order:
             bases[name] = (flat[i], flat[i + 1], flat[i + 2])
             i += 3
-        ctx = _Ctx(bases, preps, flat[i], flat[i + 1], slots)
+        ctx = _Ctx(bases, preps, narrow, flat[i], flat[i + 1], slots)
         out = _finalize(_c_lower(pplan, ctx, {}))
         captured["columns"] = out.frame.columns
         captured["cap"] = out.cap
@@ -1583,14 +1651,13 @@ def _compile_entry(fpr, pplan, preps, order, kinds, args):
         with obs.span("sql.compile.compile"):
             compiled = lowered.compile()
         t2 = time.perf_counter()
-    digest = hashlib.sha1(fpr.encode()).hexdigest()[:12]
     return _Entry(
-        compiled, captured["columns"], captured["cap"], order, digest,
+        compiled, captured["columns"], captured["cap"], order, _digest(fpr),
         t1 - t0, t2 - t1,
     )
 
 
-def _maybe_compile(fpr, pplan, preps, tables, kinds, args):
+def _maybe_compile(fpr, pplan, preps, narrow, tables, kinds, args):
     """Resolve a cache miss: compile ``fpr``, or reuse the entry a
     racing thread produced while we waited on the trace lock.  The
     caller holds the per-fingerprint lock.  Returns None on fallback."""
@@ -1601,16 +1668,16 @@ def _maybe_compile(fpr, pplan, preps, tables, kinds, args):
             _CACHE.move_to_end(fpr)
             return entry
         if fpr in _NEGATIVE:
-            STATS["fallbacks"] += 1
+            _fallback(_NEGATIVE[fpr])
             return None
         STATS["misses"] += 1
     try:
-        entry = _compile_entry(fpr, pplan, preps, tables, kinds, args)
+        entry = _compile_entry(fpr, pplan, preps, narrow, tables, kinds, args)
     except _FALLBACK_ERRORS as e:
         with _LOCK:
             _NEGATIVE[fpr] = f"{type(e).__name__}: {e}"
             _TRACE_LOCKS.pop(fpr, None)
-            STATS["fallbacks"] += 1
+            _fallback(_NEGATIVE[fpr])
         return None
     except Exception as e:
         # an *unexpected* trace/compile crash (backend bug, injected
@@ -1621,7 +1688,7 @@ def _maybe_compile(fpr, pplan, preps, tables, kinds, args):
             _NEGATIVE[fpr] = f"compile failure {type(e).__name__}: {e}"
             _TRACE_LOCKS.pop(fpr, None)
             STATS["compile_failures"] += 1
-            STATS["fallbacks"] += 1
+            _fallback(_NEGATIVE[fpr])
         return None
     with _LOCK:
         STATS["compiles"] += 1
@@ -1665,23 +1732,15 @@ def maybe_execute_compiled(plan, frames) -> Optional[TensorFrame]:
     if not scans:
         return None
     tables = sorted({s.table for s in scans})
-    for s in scans:
-        if s.predicates:
-            with _LOCK:
-                STATS["fallbacks"] += 1
-            return None
-    for t in tables:
-        if not isinstance(frames.get(t), TensorFrame):
-            with _LOCK:
-                STATS["fallbacks"] += 1
-            return None
+    if any(s.predicates for s in scans):
+        return _fallback("scan with pushed predicates")
+    if not all(isinstance(frames.get(t), TensorFrame) for t in tables):
+        return _fallback("scan of a non-frame table")
     udfs = active_udfs()
     if udfs and plan_uses_udf(plan, frozenset(udfs)):
         # the fingerprint keys on plan structure; it cannot capture the
         # python closure behind a session UDF -> op-by-op dispatch
-        with _LOCK:
-            STATS["fallbacks"] += 1
-        return None
+        return _fallback("session UDF")
     if mode != "force":
         total = sum(frames[t].nrows for t in tables)
         if total < CONFIG.compiled_min_rows:
@@ -1690,6 +1749,10 @@ def maybe_execute_compiled(plan, frames) -> Optional[TensorFrame]:
             return None
 
     preps = {t: _prep_table(frames[t]) for t in tables}
+    read: Dict[str, set] = {t: set() for t in tables}
+    for s in scans:
+        read[s.table].update(s.columns)
+    narrow = {t: _Narrow(preps[t].frame, read[t]) for t in tables}
     reqs: Dict[str, set] = {}
     _collect_unique_requests(plan, reqs)
     for t, combos in reqs.items():
@@ -1699,22 +1762,19 @@ def maybe_execute_compiled(plan, frames) -> Optional[TensorFrame]:
 
     try:
         pplan, values = parameterize(plan)
-    except Unsupported:
-        with _LOCK:
-            STATS["fallbacks"] += 1
-        return None
+    except Unsupported as e:
+        return _fallback(f"Unsupported: {e}")
     kinds = [k for k, _ in values]
     fpr = "|".join(
         [
             repr(pplan),
             f"fd={CONFIG.float_dtype}",
-            *(_table_sig(t, preps[t]) for t in tables),
+            *(_table_sig(t, preps[t], narrow[t]) for t in tables),
         ]
     )
     with _LOCK:
         if fpr in _NEGATIVE:
-            STATS["fallbacks"] += 1
-            return None
+            return _fallback(_NEGATIVE[fpr])
         entry = _CACHE.get(fpr)
         if entry is not None:
             STATS["hits"] += 1
@@ -1726,16 +1786,16 @@ def maybe_execute_compiled(plan, frames) -> Optional[TensorFrame]:
             tlock = _TRACE_LOCKS.setdefault(fpr, threading.Lock())
 
     slots, n_i, n_f = _param_slots(kinds)
-    args = _build_args(preps, tables, values, slots, n_i, n_f)
+    args = _build_args(preps, narrow, tables, values, slots, n_i, n_f)
 
     cache_hit = entry is not None
     if entry is None:
         with tlock:
-            entry = _maybe_compile(fpr, pplan, preps, tables, kinds, args)
+            entry = _maybe_compile(fpr, pplan, preps, narrow, tables, kinds, args)
         if entry is None:
             return None
         # tracing consumed (donated) the padded inputs; rebuild them
-        args = _build_args(preps, tables, values, slots, n_i, n_f)
+        args = _build_args(preps, narrow, tables, values, slots, n_i, n_f)
 
     t0 = time.perf_counter()
     with warnings.catch_warnings():

@@ -16,10 +16,9 @@ import time
 from typing import Dict, List
 
 import numpy as np
-import jax.numpy as jnp
 
 from repro import obs
-from repro.core import TensorFrame, col, if_else, lit
+from repro.core import TensorFrame, col, if_else, lit, prims
 from repro.resilience import checkpoint as _checkpoint
 from repro.resilience.faults import fault_point as _fault_point
 from repro.core.expr import DateLit, Expr
@@ -375,7 +374,7 @@ def _lower_node(
         cols = list(f.column_names)
         # keep first-occurrence row order (stable, like the oracle's
         # seen-set scan) so a later Sort+LIMIT breaks ties identically
-        rep = jnp.sort(f.groupby(cols).rep)
+        rep = prims.sort(f.groupby(cols).rep, (0, max(0, f.nrows - 1)))
         return f.take(rep, stats="subset").select(cols)
     if isinstance(node, AttachScalar):
         f = lower_plan(node.child, frames, _memo, scan_cache)

@@ -255,3 +255,16 @@ def test_exists_before_udf():
     assert hit.nrows == 2
     miss = f.filter(col("c").str.not_exists_before("special", "requests"))
     assert miss.nrows == 3
+
+
+@pytest.mark.parametrize("span", [1 << 40, 1 << 61])
+def test_sort_values_wide_keys_match_lexsort(span):
+    """Key spans whose product overflows one packed int64 code: the codes
+    are densified first, and the order stays that of a stable lexsort."""
+    rng = np.random.default_rng(span % 97)
+    a = rng.choice(rng.integers(0, span, 5), 300)  # wide, few values: ties
+    b = rng.integers(0, span, 300)
+    f = TensorFrame.from_arrays({"a": a, "b": b, "row": np.arange(300)})
+    got = f.sort_values(["a", "b"], ascending=[True, False])
+    want = np.lexsort((-b, a))
+    np.testing.assert_array_equal(np.asarray(got.column("row")), want)

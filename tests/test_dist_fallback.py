@@ -102,7 +102,6 @@ def test_quantize_roundtrip_and_error_feedback():
 
 
 def test_compressed_mean_single_device():
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     mesh = jax.make_mesh((1,), ("data",))
@@ -113,9 +112,9 @@ def test_compressed_mean_single_device():
         mean, resid = compression.compressed_mean(gl[0], "data")
         return mean[None], resid[None]
 
-    fn = shard_map(
+    fn = jax.shard_map(
         f, mesh=mesh, in_specs=(P("data"),), out_specs=(P("data"), P("data")),
-        check_rep=False,
+        check_vma=False,
     )
     mean, resid = fn(g)
     # 1-device mean == dequantized self; adding the residual restores x

@@ -40,11 +40,11 @@ def test_grad_compression_matches_exact_mean():
     run_py(
         """
 import numpy as np, jax, jax.numpy as jnp
-from jax.sharding import PartitionSpec as P
-from jax.experimental.shard_map import shard_map
+from jax.sharding import AxisType, PartitionSpec as P
 from repro.dist.compression import compressed_mean, quantize
 
-mesh = jax.make_mesh((8,), ("data",))
+# Auto axis: the outputs are indexed below like ordinary arrays
+mesh = jax.make_mesh((8,), ("data",), axis_types=(AxisType.Auto,))
 rng = np.random.default_rng(0)
 g = jnp.asarray(rng.normal(size=(8, 1024)).astype(np.float32))
 
@@ -52,7 +52,7 @@ def f(gl):
     mean, resid = compressed_mean(gl[0], "data")
     return mean[None], resid[None]
 
-fn = shard_map(f, mesh=mesh, in_specs=(P("data"),), out_specs=(P("data"), P("data")), check_rep=False)
+fn = jax.shard_map(f, mesh=mesh, in_specs=(P("data"),), out_specs=(P("data"), P("data")), check_vma=False)
 mean, resid = fn(g)
 exact = g.mean(axis=0)
 for i in range(8):

@@ -20,7 +20,7 @@ from repro.kernels.wkv6 import wkv6_pallas
 def test_hash32x2_matches_ref(n, k):
     rng = np.random.default_rng(n * 31 + k)
     cols = jnp.asarray(rng.integers(0, 2**31, size=(n, k), dtype=np.int32))
-    got = hash32x2_pallas(cols, block_rows=256)
+    got = hash32x2_pallas(cols, block_rows=256, interpret=True)
     want = ref.hash32x2(cols)
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
@@ -56,7 +56,7 @@ def test_substr_find_matches_ref(L, pat):
     strs = ["".join(rng.choice(alphabet, rng.integers(0, L))) for _ in range(733)]
     packed, lens = _pack(strs, L)
     p = jnp.asarray(np.frombuffer(pat.encode(), np.uint8))
-    got = substr_find_pallas(packed, lens, p, block_rows=128)
+    got = substr_find_pallas(packed, lens, p, block_rows=128, interpret=True)
     want = ref.substr_find(packed, lens, p)
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
     # python ground truth
@@ -76,7 +76,7 @@ def test_exists_before_matches_python():
     packed, lens = _pack(strs, 64)
     a = jnp.asarray(np.frombuffer(b"special", np.uint8))
     b = jnp.asarray(np.frombuffer(b"requests", np.uint8))
-    got = np.asarray(exists_before_pallas(packed, lens, a, b, block_rows=128))
+    got = np.asarray(exists_before_pallas(packed, lens, a, b, block_rows=128, interpret=True))
     want = np.asarray(ref.exists_before(packed, lens, a, b))
 
     def truth(s):
@@ -101,7 +101,9 @@ def test_segment_sum_sorted_matches_ref(n, m, gaps):
     else:
         m_eff = m
     vals = rng.normal(size=n).astype(np.float32)
-    got = segment_sum_sorted_pallas(jnp.asarray(vals), jnp.asarray(ids), m_eff, block_rows=256)
+    got = segment_sum_sorted_pallas(
+        jnp.asarray(vals), jnp.asarray(ids), m_eff, block_rows=256, interpret=True
+    )
     want = ref.segment_sum_sorted(jnp.asarray(vals), jnp.asarray(ids), m_eff)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=1e-5, atol=1e-5)
 
