@@ -12,6 +12,7 @@ import jax
 import jax.numpy as jnp
 
 from .frame import INT, TensorFrame, float_dtype
+from .prims import to_host
 
 AggSpec = Tuple[str, str, str]  # (out_name, fn, column) — column '' for size
 
@@ -151,6 +152,6 @@ def frame_agg(frame: TensorFrame, specs) -> Dict[str, Union[float, int]]:
                 out[out_name] = float("nan")
             continue
         v = segment_agg(frame, gids, 1, fn, colname)[0]
-        v = np.asarray(v)[()]
+        v = to_host(v, "agg.frame_agg")[()]
         out[out_name] = v.item() if hasattr(v, "item") else v
     return out

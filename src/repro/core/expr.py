@@ -24,6 +24,7 @@ import jax.numpy as jnp
 from . import encoding, strings
 from .config import CONFIG
 from .frame import INT, TensorFrame, float_dtype
+from .prims import to_host
 
 
 # ----------------------------------------------------------------------
@@ -541,7 +542,7 @@ class StrOp(Expr):
         packed, lens = strings.pack_strings_cached(dic, CONFIG.max_packed_len)
         try:
             fn = _packed_op_jitted(self.op, self.args, int(packed.shape[1]))
-            return np.asarray(fn(packed, lens))
+            return to_host(fn(packed, lens), "expr.dict_lut")
         except Exception:
             return None
 

@@ -47,9 +47,11 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from repro import obs as _obs
+
 from .config import CONFIG
 from . import encoding
-from .prims import bounds, compact
+from .prims import bounds, compact, to_host
 
 INT = jnp.int64
 # Sentinel stored in int/code slots of null cells (the hidden validity
@@ -57,6 +59,18 @@ INT = jnp.int64
 INT_NULL = np.int64(-1)
 
 VALID_PREFIX = "__v__"
+
+
+#: Bytes of the host arrays handed to the device by ``_assemble_frame``
+#: (every frame built from host columns: store scans, ``from_arrays``).
+UPLOADS = {"bytes_uploaded": 0}
+
+
+def _uploads_reset() -> None:
+    UPLOADS["bytes_uploaded"] = 0
+
+
+_obs.metrics.register_group("frame", lambda: dict(UPLOADS), _uploads_reset)
 
 
 def _valid_name(name: str) -> str:
@@ -179,7 +193,7 @@ class OffloadedColumn:
         return OffloadedColumn(self.values, self.idx[rows], self._cache)
 
     def materialize(self) -> np.ndarray:
-        return self.values[np.asarray(self.idx)]
+        return self.values[to_host(self.idx, "frame.column")]
 
     def phys_factorize(self) -> Tuple[np.ndarray, np.ndarray]:
         """(codes over physical rows, sorted dictionary), cached."""
@@ -215,7 +229,8 @@ def _assemble_frame(
     order: List[str],
     n: int,
 ) -> "TensorFrame":
-    """Stack encoded host columns into the two device tensors."""
+    """Stack encoded host columns into the two device tensors (the
+    ``frame.upload`` span; ``frame.bytes_uploaded`` counts the bytes)."""
     columns: Dict[str, ColumnMeta] = {}
     islots: Dict[str, int] = {}
     fslots: Dict[str, int] = {}
@@ -223,20 +238,24 @@ def _assemble_frame(
         islots[name] = i
     for i, (name, _) in enumerate(float_cols):
         fslots[name] = i
-    itensor = (
-        jnp.asarray(np.column_stack([c[1] for c in int_cols]).astype(np.int64))
-        if int_cols
-        else _empty_tensor(n, INT)
-    )
-    ftensor = (
-        jnp.asarray(
-            np.column_stack([c[1] for c in float_cols]).astype(
+    with _obs.span("frame.upload", rows=n) as sp:
+        nbytes = 0
+        if int_cols:
+            host = np.column_stack([c[1] for c in int_cols]).astype(np.int64)
+            nbytes += host.nbytes
+            itensor = jnp.asarray(host)
+        else:
+            itensor = _empty_tensor(n, INT)
+        if float_cols:
+            host = np.column_stack([c[1] for c in float_cols]).astype(
                 np.dtype(CONFIG.float_dtype)
             )
-        )
-        if float_cols
-        else _empty_tensor(n, float_dtype())
-    )
+            nbytes += host.nbytes
+            ftensor = jnp.asarray(host)
+        else:
+            ftensor = _empty_tensor(n, float_dtype())
+        UPLOADS["bytes_uploaded"] += nbytes
+        sp.set(bytes=nbytes)
     imeta = {name: (kind, dic) for name, _, kind, dic in int_cols}
     for name in order:
         if name in islots:
@@ -705,19 +724,21 @@ class TensorFrame:
         """
         m = self.meta(name)
         valid = self.valid_array(name)
+        if valid is not None:
+            valid = to_host(valid, "frame.column")
         if m.kind == "obj":
             out = self.offloaded[name].materialize()
             if valid is not None:
                 out = out.astype(object)
-                out[~np.asarray(valid)] = None
+                out[~valid] = None
             return out
         if m.kind == "float":
-            out = np.asarray(self._raw_values(m))
+            out = to_host(self._raw_values(m), "frame.column")
             if valid is not None:
                 out = out.copy()
-                out[~np.asarray(valid)] = np.nan
+                out[~valid] = np.nan
             return out
-        raw = np.asarray(self._raw_values(m))
+        raw = to_host(self._raw_values(m), "frame.column")
         if m.kind == "dict":
             if m.dictionary.shape[0] == 0:
                 # empty dictionary (e.g. null rows stitched against an
@@ -726,7 +747,7 @@ class TensorFrame:
             safe = np.clip(raw, 0, m.dictionary.shape[0] - 1)
             out = m.dictionary[safe].astype(object)
             if valid is not None:
-                out[~np.asarray(valid)] = None
+                out[~valid] = None
             elif (raw < 0).any():
                 out[raw < 0] = None
             return out
@@ -815,7 +836,7 @@ class TensorFrame:
         """Compact rows where mask is True (one host sync for the
         count; the payload gather is deferred behind the view)."""
         mask = jnp.asarray(mask)
-        count = int(mask.sum())
+        count = int(to_host(mask.sum(), "frame.mask_rows"))
         return self.take(compact(mask, count), stats="subset")
 
     def filter(self, expr) -> "TensorFrame":

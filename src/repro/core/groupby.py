@@ -25,6 +25,7 @@ import jax.numpy as jnp
 from . import hashing
 from .agg import normalize_specs, segment_agg
 from .frame import INT, TensorFrame
+from .prims import to_host
 
 
 class GroupBy:
@@ -142,10 +143,10 @@ def transposed_group_ids(cols: Sequence[np.ndarray]) -> np.ndarray:
     if arrs[0].shape[0]:
         # all k cardinalities in ONE device fetch (was: one int(a.max())
         # host sync per key column)
-        cards = np.asarray(jnp.stack([a.max() for a in arrs])) + 1
+        cards = to_host(jnp.stack([a.max() for a in arrs]), "groupby.transposed") + 1
     else:
         cards = np.ones((len(arrs),), dtype=np.int64)
     for a, card in zip(arrs, cards):
         packed = packed * np.int64(max(1, int(card))) + a
     _, gids, _ = hashing.distinct(packed)
-    return np.asarray(gids)
+    return to_host(gids, "groupby.transposed")

@@ -166,7 +166,7 @@ def distinct(
         present, ranks = (_dense_rank_jit if jit else _dense_rank)(
             packed, domain
         )
-        m = int(ranks[-1]) + 1  # the one host sync
+        m = int(prims.to_host(ranks[-1], "hashing.distinct")) + 1
         uniques, gids = (_dense_compact_jit if jit else _dense_compact)(
             packed, present, ranks, m
         )
@@ -177,7 +177,7 @@ def distinct(
         [jnp.ones((1,), dtype=bool), sp[1:] != sp[:-1]]
     )
     ranks = prims.prefix_sum(boundary.astype(INT)) - 1  # group id, sorted order
-    m = int(ranks[-1]) + 1  # the one host sync
+    m = int(prims.to_host(ranks[-1], "hashing.distinct")) + 1
     uniques = jnp.zeros((m,), dtype=packed.dtype).at[ranks].set(sp)
     gids = jnp.zeros((n,), dtype=INT).at[order].set(ranks)
     return uniques, gids, m

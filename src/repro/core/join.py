@@ -235,7 +235,7 @@ def direct_address_rows(
     if npr == 0:
         empty = jnp.zeros((0,), dtype=INT)
         return matched, empty, empty
-    cnt = int(slots[-1])  # the one host sync of the probe
+    cnt = int(prims.to_host(slots[-1], "join.direct_probe"))  # the one sync
     compact_fn = _dar_compact_jit if use_jit else _dar_compact
     probe_rows, build_rows = compact_fn(pos, matched, slots, cnt)
     return matched, probe_rows, build_rows
@@ -259,7 +259,7 @@ def sorted_probe_rows(
     starts = jnp.searchsorted(sb, probe, side="left")
     ends = jnp.searchsorted(sb, probe, side="right")
     counts = (ends - starts).astype(INT)
-    total = int(counts.sum())  # the one host sync of the probe
+    total = int(prims.to_host(counts.sum(), "join.sorted_probe"))  # the one sync
     return (counts,) + _expand_runs(starts.astype(INT), counts, order, total)
 
 
@@ -565,7 +565,8 @@ def join(
                 # of the key combination — unless null keys collapsed
                 # codes, which would under-count)
                 STATS["uniqueness_sort_tests"] += 1
-                m_build = int((jnp.diff(prims.sort(rcodes)) != 0).sum()) + 1
+                steps = (jnp.diff(prims.sort(rcodes)) != 0).sum()
+                m_build = int(prims.to_host(steps, "join.unique_test")) + 1
                 unique_build = m_build == nb
                 if not null_keys:
                     right.set_stats(
@@ -600,7 +601,7 @@ def join(
     if matched_counts is None:  # sortmerge path
         matched_counts = jnp.zeros((left.nrows,), dtype=INT).at[lrows].add(1)
     unmatched = matched_counts == 0
-    n_un = int(unmatched.sum())
+    n_un = int(prims.to_host(unmatched.sum(), "join.left_outer"))
     outer_part = _hstack(left.mask_rows(unmatched), _null_right_rows(right, n_un), name_map)
 
     # every right output column must carry validity in both parts;

@@ -94,9 +94,11 @@ _INT_DOMAIN = ("int", "date", "bool")
 class ChunkScan:
     """Iterate a predicated store scan as per-chunk TensorFrames.
 
-    Chunk decode (numpy: rle expansion, row masks, validity) runs on a
-    prefetch thread up to ``CONFIG.ooc_prefetch`` chunks ahead; the
-    consuming thread only does the host->device transfer and compute.
+    Chunk decode (numpy: rle expansion, row masks, validity; the
+    ``store.scan`` spans) runs on a prefetch thread up to
+    ``CONFIG.ooc_prefetch`` chunks ahead; the consuming thread only does
+    the host->device transfer and compute, and waits on the decode in
+    ``pipeline.prefetch_wait`` spans.
     ``prefetch=0`` degrades to fully synchronous iteration.
     """
 
@@ -118,12 +120,9 @@ class ChunkScan:
         depth = max(0, int(CONFIG.ooc_prefetch))
         if depth == 0 or len(self.survivors) <= 1:
             for i in self.survivors:
-                with _obs.detailed_span(
-                    "pipeline.chunk_decode", chunk=int(i)
-                ):
-                    res = _store.scan_chunk(
-                        self.table, self.proj, self.phys_preds, int(i)
-                    )
+                res = _store.scan_chunk(
+                    self.table, self.proj, self.phys_preds, int(i)
+                )
                 yield int(i), res
             return
         q: "queue.Queue" = queue.Queue(maxsize=depth)
@@ -147,12 +146,9 @@ class ChunkScan:
                 for i in self.survivors:
                     if stop.is_set():
                         return
-                    with _obs.detailed_span(
-                        "pipeline.chunk_decode", chunk=int(i)
-                    ):
-                        res = _store.scan_chunk(
-                            self.table, self.proj, self.phys_preds, int(i)
-                        )
+                    res = _store.scan_chunk(
+                        self.table, self.proj, self.phys_preds, int(i)
+                    )
                     if not put((int(i), res)):
                         return
             except BaseException as e:  # re-raised on the consumer side
@@ -160,11 +156,13 @@ class ChunkScan:
             finally:
                 put(DONE)
 
-        t = threading.Thread(target=producer, daemon=True)
+        # the producer's spans (``store.scan``) join the consumer's
+        # query: same trace id, parented to the span open here
+        t = threading.Thread(target=_obs.bind(producer), daemon=True)
         t.start()
         try:
             while True:
-                with _obs.detailed_span("pipeline.prefetch_wait"):
+                with _obs.span("pipeline.prefetch_wait"):
                     item = q.get()
                 if item is DONE:
                     break
@@ -279,7 +277,8 @@ class HashBuild:
         hint = self.build.unique_hint(self.build_keys)
         if hint is None:
             # pay the sort test ONCE at build time (not per chunk)
-            m_build = int((jnp.diff(prims.sort(codes)) != 0).sum()) + 1
+            steps = (jnp.diff(prims.sort(codes)) != 0).sum()
+            m_build = int(prims.to_host(steps, "pipeline.unique_test")) + 1
             hint = m_build == nb
             self.build.set_stats(
                 self.build_keys[0], unique=bool(hint), distinct=m_build
@@ -355,7 +354,7 @@ class HashBuild:
                 )
                 pos = payload[probe_idx]
                 matched = pos >= 0
-                cnt = int(matched.sum())  # the one sync per chunk
+                cnt = int(prims.to_host(matched.sum(), "pipeline.probe_chunk"))
                 lrows = prims.compact(matched, cnt)
                 rrows = pos[lrows]
                 name_map = _right_name_map(

@@ -22,18 +22,51 @@ so the engine uses these forms instead:
 Inside a traced program (no host sync for bounds) use
 ``lax.sort(x, is_stable=False)`` for one-operand sorts: stability means
 nothing there, and the stable form compiles three times slower.
+
+Every device->host fetch of the engine goes through ``to_host``, which
+counts it (``obs.metrics`` group ``device``: ``host_syncs`` and
+``by_site``) and, with tracing on, times it as a ``device.sync`` span.
 """
 from __future__ import annotations
 
 from functools import partial
-from typing import Optional, Tuple
+from typing import Dict, Optional, Tuple
 
 import numpy as np
 import jax
 import jax.numpy as jnp
 from jax import lax
 
+from repro import obs as _obs
+
 INT = jnp.int64
+
+#: Device->host fetches since the last reset, per call site.  A plain
+#: dict increment, counted with tracing off too, like the engine's other
+#: STATS dicts.
+SYNC_SITES: Dict[str, int] = {}
+
+
+def _syncs_snapshot() -> Dict:
+    sites = dict(SYNC_SITES)
+    return {"host_syncs": sum(sites.values()), "by_site": sites}
+
+
+_obs.metrics.register_group("device", _syncs_snapshot, SYNC_SITES.clear)
+
+
+def to_host(x: jax.Array, site: str) -> np.ndarray:
+    """``np.asarray(x)``: wait for the device, copy ``x`` to the host.
+
+    The engine's one device->host fetch: ``site`` names the caller in
+    the per-site counts and on the ``device.sync`` span, whose time is
+    the host blocked on the device.  It is also the one place that lets
+    a transfer through ``jax.transfer_guard_device_to_host``."""
+    SYNC_SITES[site] = SYNC_SITES.get(site, 0) + 1
+    with _obs.span("device.sync", site=site), jax.transfer_guard_device_to_host(
+        "allow"
+    ):
+        return np.asarray(x)
 
 
 def _log_step_scan(x: jax.Array, combine, identity) -> jax.Array:
@@ -89,7 +122,7 @@ def _minmax(x: jax.Array) -> jax.Array:
 
 def bounds(x: jax.Array) -> Tuple[int, int]:
     """``(min, max)`` of a non-empty integer array, in one fetch."""
-    lo, hi = np.asarray(_minmax(x)).tolist()
+    lo, hi = to_host(_minmax(x), "prims.bounds").tolist()
     return int(lo), int(hi)
 
 

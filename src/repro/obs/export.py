@@ -52,7 +52,7 @@ def export_chrome_trace(
                 "dur": s.dur_ns / 1e3,
                 "pid": pid,
                 "tid": s.tid,
-                "args": _span_args(s.attrs),
+                "args": {**_span_args(s.attrs), "trace_id": s.trace_id},
             }
         )
         names.setdefault(s.tid, s.thread)

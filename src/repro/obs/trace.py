@@ -1,10 +1,10 @@
 """Span tracer: nestable timed regions in per-thread ring buffers.
 
 ``span("join.direct_probe", rows=...)`` opens a context manager that
-records name, monotonic wall-clock interval, thread, parent span, and
-free-form attributes on exit.  Records land in a fixed-capacity ring
-buffer owned by the writing thread — appends take no lock (only the
-owner writes; readers snapshot under the GIL), so tracing from the
+records name, monotonic wall-clock interval, thread, parent span, trace
+id and free-form attributes on exit.  Records land in a fixed-capacity
+ring buffer owned by the writing thread — appends take no lock (only
+the owner writes; readers snapshot under the GIL), so tracing from the
 serve worker, prefetch threads, and client threads never contend.
 
 Toggled by ``CONFIG.tracing``:
@@ -12,24 +12,37 @@ Toggled by ``CONFIG.tracing``:
 - ``"off"`` (default): ``span()`` returns one shared no-op context
   manager — a single branch, no allocation, no clock read;
 - ``"on"``: operator-level spans record (plan nodes, joins, compile
-  phases, serve batch phases, pipeline/spill events);
+  phases, serve batch phases, store scans, uploads, device syncs,
+  prefetch waits);
 - ``"detailed"``: additionally records per-chunk spans
-  (``detailed_span``): chunk decode, prefetch waits, per-chunk probes.
+  (``detailed_span``): per-chunk probes and partial aggregates.
+
+Every recorded span also enters a ``jax.profiler.TraceAnnotation`` of
+the same name carrying its ``trace_id``: inside a profiler session the
+spans land on the host threads' lines of the trace, on the clock of the
+device's ops; outside one the annotation is a no-op TraceMe.
+
+``trace_scope(trace_id)`` tags every span opened under it (in this
+thread, and in threads started through ``bind``) with one identifier,
+so the spans of one query line up across threads.
 
 This module must import without jax (CI-enforced via ``repro.obs``);
-``CONFIG`` is resolved lazily on first use, mirroring
-``repro.store.spill``.
+``CONFIG`` and ``jax.profiler`` are resolved lazily on first use,
+mirroring ``repro.store.spill``.
 """
 from __future__ import annotations
 
+import contextlib
+import contextvars
 import itertools
 import threading
 import time
-from typing import Any, Dict, List, NamedTuple, Optional
+from typing import Any, Callable, Dict, Iterator, List, NamedTuple, Optional, Tuple
 
 __all__ = [
     "SpanRecord",
     "annotate",
+    "bind",
     "clear",
     "current_span_id",
     "detailed",
@@ -37,6 +50,7 @@ __all__ = [
     "enabled",
     "span",
     "spans",
+    "trace_scope",
 ]
 
 _CFG = None
@@ -51,6 +65,18 @@ def _cfg():
     return _CFG
 
 
+_ANNOTATION = None
+
+
+def _annotation():
+    global _ANNOTATION
+    if _ANNOTATION is None:
+        from jax.profiler import TraceAnnotation  # lazy: keeps obs jax-free
+
+        _ANNOTATION = TraceAnnotation
+    return _ANNOTATION
+
+
 #: Per-thread ring capacity (spans).  A full ring overwrites its oldest
 #: records and counts them in ``dropped``.
 CAPACITY = 1 << 16
@@ -58,6 +84,12 @@ CAPACITY = 1 << 16
 _IDS = itertools.count(1)  # next() is atomic under the GIL
 _LOCK = threading.Lock()
 _RINGS: List["_Ring"] = []
+
+#: ``(trace_id, parent of this context's top-level spans)``: set per
+#: query by ``trace_scope``, carried into worker threads by ``bind``.
+_CTX: "contextvars.ContextVar[Tuple[int, int]]" = contextvars.ContextVar(
+    "repro_obs_trace", default=(0, 0)
+)
 
 
 class SpanRecord(NamedTuple):
@@ -69,20 +101,22 @@ class SpanRecord(NamedTuple):
     span_id: int
     parent_id: int  # 0 = top-level
     attrs: Optional[Dict[str, Any]]
+    trace_id: int  # 0 = outside any trace_scope
 
 
 class _Ring:
     """Fixed-capacity overwrite-oldest buffer; single-writer."""
 
-    __slots__ = ("buf", "cap", "i", "dropped", "tid", "thread")
+    __slots__ = ("buf", "cap", "i", "dropped", "owner", "tid", "thread")
 
-    def __init__(self, cap: int, tid: int, thread: str) -> None:
+    def __init__(self, cap: int, owner: threading.Thread) -> None:
         self.buf: List[SpanRecord] = []
         self.cap = cap
         self.i = 0
         self.dropped = 0
-        self.tid = tid
-        self.thread = thread
+        self.owner = owner  # ``clear`` drops the rings of exited owners
+        self.tid = owner.ident or 0
+        self.thread = owner.name
 
     def append(self, rec: SpanRecord) -> None:
         if len(self.buf) < self.cap:
@@ -97,9 +131,8 @@ class _State(threading.local):
     """Per-thread open-span stack + ring, created on first span."""
 
     def __init__(self) -> None:
-        t = threading.current_thread()
         self.stack: List["_Span"] = []
-        self.ring = _Ring(CAPACITY, t.ident or 0, t.name)
+        self.ring = _Ring(CAPACITY, threading.current_thread())
         with _LOCK:
             _RINGS.append(self.ring)
 
@@ -128,7 +161,7 @@ _NOOP = _NoopSpan()
 
 
 class _Span:
-    __slots__ = ("name", "attrs", "t0", "span_id", "parent_id")
+    __slots__ = ("name", "attrs", "t0", "span_id", "parent_id", "trace_id", "ann")
 
     def __init__(self, name: str, attrs: Optional[Dict[str, Any]]) -> None:
         self.name = name
@@ -142,14 +175,20 @@ class _Span:
 
     def __enter__(self) -> "_Span":
         st = _STATE
-        self.parent_id = st.stack[-1].span_id if st.stack else 0
+        self.trace_id, root = _CTX.get()
+        self.parent_id = st.stack[-1].span_id if st.stack else root
         self.span_id = next(_IDS)
         st.stack.append(self)
+        self.ann = (_ANNOTATION or _annotation())(
+            self.name, trace_id=self.trace_id
+        )
+        self.ann.__enter__()
         self.t0 = time.perf_counter_ns()
         return self
 
     def __exit__(self, *exc) -> None:
         t1 = time.perf_counter_ns()
+        self.ann.__exit__(None, None, None)
         st = _STATE
         if st.stack and st.stack[-1] is self:
             st.stack.pop()
@@ -168,6 +207,7 @@ class _Span:
                 self.span_id,
                 self.parent_id,
                 self.attrs,
+                self.trace_id,
             )
         )
 
@@ -212,6 +252,38 @@ def current_span_id() -> int:
     return st.stack[-1].span_id if st.stack else 0
 
 
+@contextlib.contextmanager
+def trace_scope(trace_id: int) -> Iterator[None]:
+    """``with trace_scope(query_id): ...`` — every span opened inside
+    (on this thread, or on threads started through ``bind``) records
+    ``trace_id=query_id``.  Costs one context-variable set and reset,
+    whether or not tracing is on."""
+    token = _CTX.set((trace_id, 0))
+    try:
+        yield
+    finally:
+        _CTX.reset(token)
+
+
+def bind(fn: Callable) -> Callable:
+    """Wrap ``fn`` to run in a copy of the caller's context, for a
+    thread the caller starts: its spans carry the caller's trace id,
+    and its top-level spans name as parent the span open in the caller
+    now."""
+    ctx = contextvars.copy_context()
+    scope = (_CTX.get()[0], current_span_id())
+
+    def run(*args, **kwargs):
+        return ctx.copy().run(_run_in, scope, fn, args, kwargs)
+
+    return run
+
+
+def _run_in(scope, fn, args, kwargs):
+    _CTX.set(scope)
+    return fn(*args, **kwargs)
+
+
 def spans(since_ns: Optional[int] = None) -> List[SpanRecord]:
     """Snapshot every thread's recorded spans, oldest first."""
     with _LOCK:
@@ -232,8 +304,12 @@ def dropped() -> int:
 
 def clear() -> None:
     """Drop all recorded spans (open spans on other threads may lose
-    their record — tracing is best-effort by design)."""
+    their record — tracing is best-effort by design), and the rings of
+    threads that have exited: a serving process starts a prefetch
+    thread per streamed query, and each would otherwise keep its ring
+    for the life of the process."""
     with _LOCK:
+        _RINGS[:] = [r for r in _RINGS if r.owner.is_alive()]
         for r in _RINGS:
             r.buf = []
             r.i = 0

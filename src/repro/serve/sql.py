@@ -131,6 +131,12 @@ class _Request:
         self._owner._resolve(self, out)
 
 
+def _member_ids(group: List[_Request]) -> str:
+    """The request ids of a coalesced group (its trace id is the
+    first), as a span attribute."""
+    return ",".join(str(m.request_id) for m in group)
+
+
 class _GroupToken:
     """Cancel view of a coalesced group: cancelled only when *every*
     member asked to cancel — one client must not kill a result other
@@ -465,7 +471,9 @@ class Executor:
             req = group[0]
             t0 = time.perf_counter()
             try:
-                with obs.span("serve.plan"), udf_scope(req.udfs):
+                with obs.trace_scope(req.request_id), obs.span(
+                    "serve.plan", members=_member_ids(group)
+                ), udf_scope(req.udfs):
                     req.plan = sql.plan_query(
                         req.text, frames, optimized=True
                     )
@@ -569,9 +577,9 @@ class Executor:
         t0 = time.perf_counter()
         c0 = self._compile_seconds()
         try:
-            with obs.span("serve.execute", queries=len(group)), udf_scope(
-                req.udfs
-            ), deadline_scope(
+            with obs.trace_scope(req.request_id), obs.span(
+                "serve.execute", queries=len(group), members=_member_ids(group)
+            ), udf_scope(req.udfs), deadline_scope(
                 at=self._group_deadline(group),
                 token=_GroupToken([m.token for m in group]),
             ):

@@ -63,6 +63,10 @@ class ServeStats:
         self._shed: Dict[str, int] = {}  # shed reason -> count
         self._lat: List[float] = []  # seconds, bounded reservoir
         self._phase: Dict[str, List[float]] = {p: [] for p in PHASES}
+        # running totals beside the reservoirs: they never halve, so a
+        # before/after diff sums a window's time exactly
+        self._phase_total = {p: 0.0 for p in PHASES}
+        self._phase_n = {p: 0 for p in PHASES}
 
     def bump(self, **deltas: int) -> None:
         with self._lock:
@@ -95,9 +99,12 @@ class ServeStats:
 
     def record_phase(self, phase: str, seconds: float) -> None:
         """Record one query's time in an admission/execution phase
-        (``PHASES``); each phase keeps its own percentile reservoir."""
+        (``PHASES``); each phase keeps its own percentile reservoir and
+        running ``total_ms``/``n``."""
         with self._lock:
             self._push(self._phase[phase], seconds)
+            self._phase_total[phase] += seconds * 1e3
+            self._phase_n[phase] += 1
 
     def percentiles(self) -> Dict[str, float]:
         """p50/p90/p95/p99 end-to-end latency in milliseconds."""
@@ -109,16 +116,22 @@ class ServeStats:
         )
 
     def phase_percentiles(self) -> Dict[str, Dict[str, float]]:
-        """Per-phase p50/p95/p99 (ms): where a served query's latency
-        goes — queueing, planning, compiling, or executing."""
+        """Per-phase p50/p95/p99 (ms) over the reservoir (``count``
+        samples), and the running ``total_ms`` and ``n`` since the last
+        reset: where a served query's latency goes — queueing,
+        planning, compiling, or executing."""
         with self._lock:
             phases = {p: list(lat) for p, lat in self._phase.items()}
+            totals = dict(self._phase_total)
+            ns = dict(self._phase_n)
         return {
             p: dict(
                 _quantiles(
                     lat, {"p50_ms": 0.50, "p95_ms": 0.95, "p99_ms": 0.99}
                 ),
                 count=len(lat),
+                total_ms=totals[p],
+                n=ns[p],
             )
             for p, lat in phases.items()
         }
@@ -142,6 +155,8 @@ class ServeStats:
             self._shed = {}
             self._lat = []
             self._phase = {p: [] for p in PHASES}
+            self._phase_total = {p: 0.0 for p in PHASES}
+            self._phase_n = {p: 0 for p in PHASES}
 
     def __getitem__(self, key: str) -> int:
         with self._lock:

@@ -421,7 +421,7 @@ def _ensure_unique_locked(prep: _PrepTable, cols: Tuple[str, ...]) -> bool:
             if f.nrows == 0:
                 hint = True
             else:
-                arrs = [np.asarray(f.col_values(c)) for c in key]
+                arrs = [prims.to_host(f.col_values(c), "compile.unique_test") for c in key]
                 if len(arrs) == 1:
                     hint = int(np.unique(arrs[0]).size) == f.nrows
                 else:
@@ -1805,7 +1805,7 @@ def maybe_execute_compiled(plan, frames) -> Optional[TensorFrame]:
             "sql.compile.execute", digest=entry.digest, cache_hit=cache_hit
         ) as sp:
             it, ft, n_out = entry.compiled(*args)
-            n = int(n_out)  # host sync: the program really ran
+            n = int(prims.to_host(n_out, "compile.rows"))  # the program really ran
             sp.set(rows=n)
     t1 = time.perf_counter()
     with _LOCK:

@@ -29,9 +29,12 @@ conjuncts).
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
+
+from repro import obs as _obs
 
 from .table import Column, Table, _empty_physical
 
@@ -359,9 +362,23 @@ def _eval_rows(
     return mask
 
 
+def _traced(fn):
+    """Run ``fn`` inside a ``store.scan`` span: the host time of the
+    store's read primitives (``scan``, ``scan_chunk``, ``shared_scan``,
+    none of which calls another, so the spans never nest)."""
+
+    @functools.wraps(fn)
+    def wrapper(*args, **kwargs):
+        with _obs.span("store.scan", via=fn.__name__):
+            return fn(*args, **kwargs)
+
+    return wrapper
+
+
 # ----------------------------------------------------------------------
 # the scan
 # ----------------------------------------------------------------------
+@_traced
 def scan(
     table: Table,
     columns: Optional[Sequence[str]] = None,
@@ -494,6 +511,7 @@ def plan_scan(
     return proj, phys_preds, survivors
 
 
+@_traced
 def scan_chunk(
     table: Table,
     proj: Sequence[str],
@@ -541,6 +559,7 @@ def scan_chunk(
 # ----------------------------------------------------------------------
 # shared multi-predicate scan (serving micro-batches)
 # ----------------------------------------------------------------------
+@_traced
 def shared_scan(
     table: Table,
     requests: Sequence[Tuple[Optional[Sequence[str]], Sequence[Pred]]],
