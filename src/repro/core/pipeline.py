@@ -17,31 +17,42 @@ pipeline streams it chunk by chunk:
   provably-unique inner builds, sorted membership codes for semi/anti.
   Probe chunks whose key bounds miss the build's range are skipped
   outright (inner/semi) or passed through unprobed (anti).
-- ``StreamAgg`` accumulates per-chunk partial aggregates (mean
-  decomposes into sum+count) and re-aggregates the partials every
-  ``CONFIG.ooc_merge_every`` chunks.  Partial blocks live under the
+- ``StreamAgg`` folds each chunk into partial aggregates (mean
+  decomposes into sum+count).  Where every group key is a non-null
+  dictionary or bool column, the key domain is known on the host, and
+  small, each chunk folds into device-resident ``(D,)`` accumulators
+  with one jitted program and no fetch, at most ``CONFIG.ooc_prefetch``
+  folds queued on the device; they come to the host once, at finalize.  Other keys (int, date, nullable, large domains) take
+  per-chunk partial blocks re-aggregated every
+  ``CONFIG.ooc_merge_every`` chunks.  Those blocks live under the
   spill manager (``repro.store.spill``), so a run under
   ``CONFIG.memory_budget_bytes`` keeps its working set bounded: cold
   partials go to ``.tfb`` chunk files and re-hydrate transparently.
 
 ``STATS`` makes the whole thing observable: chunks streamed/pruned,
-rows streamed, pipeline and fallback counts, plus the spill manager's
-bytes spilled/re-read, evictions and peak tracked bytes.
+rows streamed, chunks folded into a dense device accumulator
+(``dense_agg_chunks``), pipeline and fallback counts, plus the spill
+manager's bytes spilled/re-read, evictions and peak tracked bytes.
 """
 from __future__ import annotations
 
+import collections
+import math
 import queue
 import threading
-from typing import Dict, List, Optional, Sequence, Tuple
+from functools import partial
+from typing import Deque, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
+import jax
 import jax.numpy as jnp
 
 from repro.resilience import checkpoint
 
 from . import prims
+from .agg import _num_values
 from .config import CONFIG
-from .frame import INT, TensorFrame
+from .frame import INT, TensorFrame, _assemble_frame, _valid_name
 from .join import _hstack, _right_name_map, member_sorted
 
 #: Observable pipeline counters.  The spill keys mirror
@@ -51,6 +62,7 @@ STATS = {
     "chunks_streamed": 0,
     "chunks_pruned": 0,
     "rows_streamed": 0,
+    "dense_agg_chunks": 0,
     "partial_merges": 0,
     "generic_probes": 0,
     "fallbacks": 0,
@@ -381,6 +393,213 @@ class HashBuild:
 
 
 # ----------------------------------------------------------------------
+# dense device accumulators for small, host-known key domains
+# ----------------------------------------------------------------------
+#: Key domains (the product of the key cardinalities) up to this many
+#: groups fold into device-resident accumulators.  A chunk folds as a
+#: masked reduction over a ``(D, n)`` key compare, which grows with D:
+#: on a v5e at n = 65,536 it took 1.1 ms at D = 6 to 256 and 3.1 ms at
+#: 1,024 (``PERF.md``, Streamed path).  Larger domains keep the
+#: per-chunk partial blocks.
+DENSE_MAX_GROUPS = 1024
+
+
+def _identity(op: str, dtype):
+    """The value an empty group holds: 0 for sums and counts, the
+    dtype's largest (smallest) value for min (max)."""
+    if op in ("sum", "count"):
+        return 0
+    if jnp.issubdtype(dtype, jnp.floating):
+        return np.inf if op == "min" else -np.inf
+    info = np.iinfo(dtype)
+    return info.max if op == "min" else info.min
+
+
+@partial(jax.jit, static_argnames=("domain", "spec"))
+def _dense_init(*, domain: int, spec: Tuple[Tuple[str, str], ...]):
+    return tuple(
+        jnp.full((domain,), _identity(op, jnp.dtype(dt)), dtype=dt)
+        for op, dt in spec
+    )
+
+
+@partial(jax.jit, static_argnames=("keys", "cols", "valids", "cards", "ops"))
+def _dense_fold(acc, itensor, ftensor, *, keys, cols, valids, cards, ops):
+    """Fold one chunk, read straight from its two tensors, into the
+    ``(D,)`` accumulators ``acc``: the row count of each group first,
+    then one per partial.  ``keys`` are the key columns' slots in
+    ``itensor``.  Per partial: ``ops`` is ``sum``/``count``/``min``/
+    ``max``; ``cols`` its values' ``(is_float, slot)``, None for a
+    count, which reads only validity; ``valids`` the slot of its
+    validity column in ``itensor``, None when every row is valid."""
+    n = itensor.shape[0]
+    domain = math.prod(cards)
+    packed = jnp.zeros((n,), dtype=INT)
+    for slot, card in zip(keys, cards):  # Horner, as hashing.composite_key
+        packed = packed * np.int64(card) + itensor[:, slot]
+    hit = jnp.arange(domain, dtype=INT)[:, None] == packed[None, :]
+
+    def reduce(v, op):  # a masked reduction: no scatter
+        r = jnp.where(hit, v[None, :], _identity(op, v.dtype))
+        if op == "min":
+            return r.min(axis=1)
+        return r.max(axis=1) if op == "max" else r.sum(axis=1)
+
+    ones = jnp.ones((n,), dtype=INT)
+    out = [acc[0] + reduce(ones, "count")]
+    for a, src, vslot, op in zip(acc[1:], cols, valids, ops):
+        valid = None if vslot is None else itensor[:, vslot] != 0
+        if op == "count":
+            v = ones if valid is None else valid.astype(INT)
+        else:
+            is_float, slot = src
+            v = (ftensor if is_float else itensor)[:, slot]
+            if valid is not None:
+                v = jnp.where(valid, v, _identity(op, v.dtype))
+        r = reduce(v, op)
+        if op == "min":
+            out.append(jnp.minimum(a, r))
+        elif op == "max":
+            out.append(jnp.maximum(a, r))
+        else:
+            out.append(a + r)
+    return tuple(out)
+
+
+class DenseAcc:
+    """Device-resident ``(D,)`` accumulators of one keyed streamed
+    aggregate.
+
+    Engages when every group key is a dictionary or bool column with
+    no validity: a group's packed code (Horner over the key codes)
+    then indexes a domain whose size ``D``, the product of the
+    dictionaries' lengths (2 for a bool), is known on the host without
+    a fetch.  Each chunk folds in with one jitted program, with at most
+    ``CONFIG.ooc_prefetch`` folds queued on the device (``_pace``); the
+    accumulators come to the host once (``fetch``).  Groups no row
+    reached are dropped there; the rest come out in ascending packed
+    code, keys carrying the chunks' own dictionaries.
+    """
+
+    def __init__(self, keys, partials, kinds, dictionaries, cards, ops, acc):
+        self.keys = keys
+        self.partials = partials
+        self.kinds = kinds
+        self.dictionaries = dictionaries
+        self.cards = cards
+        self.ops = ops
+        self.acc = acc
+        self._inflight: Deque = collections.deque()  # see ``_pace``
+
+    @classmethod
+    def open(
+        cls, f: TensorFrame, keys: List[str], partials
+    ) -> Optional["DenseAcc"]:
+        """Accumulators shaped by the first chunk ``f``, or None when
+        its keys do not qualify."""
+        kinds, dictionaries, cards = [], [], []
+        for k in keys:
+            m = f.meta(k)
+            if m.kind not in ("dict", "bool") or f.has_nulls(k):
+                return None
+            kinds.append(m.kind)
+            dictionaries.append(m.dictionary)
+            cards.append(2 if m.kind == "bool" else int(m.dictionary.shape[0]))
+        domain = math.prod(cards)
+        if not 0 < domain <= DENSE_MAX_GROUPS:
+            return None
+        ops = tuple("count" if fn == "size" else fn for _, fn, _ in partials)
+        spec = [("count", "int64")]
+        for (_, fn, colname), op in zip(partials, ops):
+            dt = "int64" if op == "count" else str(_num_values(f, colname).dtype)
+            spec.append((op, dt))
+        acc = _dense_init(domain=domain, spec=tuple(spec))
+        return cls(keys, partials, kinds, dictionaries, tuple(cards), ops, acc)
+
+    def accepts(self, f: TensorFrame) -> bool:
+        """Do ``f``'s keys code into the same domain?  A dictionary key
+        must carry the very same dictionary object."""
+        for k, kind, dic in zip(self.keys, self.kinds, self.dictionaries):
+            m = f.meta(k)
+            if m.kind != kind or f.has_nulls(k):
+                return False
+            if kind == "dict" and m.dictionary is not dic:
+                return False
+        return True
+
+    def fold(self, f: TensorFrame) -> None:
+        f.materialize()  # one program reads every column of the chunk
+        cols, valids = [], []
+        for (_, _, colname), op in zip(self.partials, self.ops):
+            if op == "count":
+                cols.append(None)
+            else:
+                m = f.meta(colname)
+                cols.append((m.kind == "float", m.slot))
+            nullable = f.has_nulls(colname)  # never for a size ("")
+            valids.append(f.meta(_valid_name(colname)).slot if nullable else None)
+        self.acc = _dense_fold(
+            self.acc,
+            f.itensor,
+            f.ftensor,
+            keys=tuple(f.meta(k).slot for k in self.keys),
+            cols=tuple(cols),
+            valids=tuple(valids),
+            cards=self.cards,
+            ops=self.ops,
+        )
+        self._pace()
+
+    def _pace(self) -> None:
+        """Keep at most ``CONFIG.ooc_prefetch`` (at least one) folds
+        queued on the device: block on the accumulators of the fold that
+        many chunks back.  Nothing else in the loop waits for the
+        device, and each queued fold holds its chunk's device buffers,
+        so without this a host that outruns the device would pile up
+        uploads for the whole stream."""
+        self._inflight.append(self.acc)
+        if len(self._inflight) > max(1, int(CONFIG.ooc_prefetch)):
+            with _obs.span("pipeline.fold_wait"):
+                jax.block_until_ready(self._inflight.popleft())
+
+    def fetch(self) -> Tuple[List[np.ndarray], List[np.ndarray]]:
+        """(key codes, partial values) of the groups some row reached,
+        in ascending packed code: the one device->host fetch."""
+        got = prims.to_host(self.acc, "pipeline.dense_agg")
+        present = np.flatnonzero(got[0] > 0)
+        codes, rest = [], present
+        for card in reversed(self.cards):
+            codes.append(rest % card)
+            rest = rest // card
+        return codes[::-1], [np.asarray(g)[present] for g in got[1:]]
+
+    def frame(self, order: List[str]) -> TensorFrame:
+        """The accumulated groups as a frame with columns ``order``
+        (keys, then partials)."""
+        codes, vals = self.fetch()
+        int_cols, float_cols = [], []
+        for k, kind, dic, c in zip(self.keys, self.kinds, self.dictionaries, codes):
+            int_cols.append((k, c, kind, dic if kind == "dict" else None))
+        for (pn, _, _), v in zip(self.partials, vals):
+            if np.issubdtype(v.dtype, np.floating):
+                float_cols.append((pn, v))
+            else:
+                int_cols.append((pn, v, "int", None))
+        return _assemble_frame(int_cols, float_cols, {}, order, codes[0].shape[0])
+
+    def block(self) -> Dict[str, np.ndarray]:
+        """The accumulated groups as a host partial block, in the form
+        ``StreamAgg`` spills (``TensorFrame.column`` values)."""
+        codes, vals = self.fetch()
+        out: Dict[str, np.ndarray] = {}
+        for k, kind, dic, c in zip(self.keys, self.kinds, self.dictionaries, codes):
+            out[k] = dic[c].astype(object) if kind == "dict" else c != 0
+        for (pn, _, _), v in zip(self.partials, vals):
+            out[pn] = v
+        return out
+
+
+# ----------------------------------------------------------------------
 # streaming group-by aggregation
 # ----------------------------------------------------------------------
 _PARTIAL_MERGE = {"sum": "sum", "count": "sum", "size": "sum",
@@ -390,14 +609,18 @@ STREAMABLE_AGGS = frozenset(("sum", "count", "size", "min", "max", "mean"))
 
 
 class StreamAgg:
-    """Accumulate per-chunk partial aggregates; merge under the budget.
+    """Fold chunk frames into partial aggregates.
 
     ``specs`` are engine agg specs ``(out_name, fn, column)`` with
     ``fn`` in ``STREAMABLE_AGGS``.  Mean decomposes into sum+count
-    partials and reassembles at finalize.  Keyed partials are host
-    blocks registered with the spill manager — under a tight
-    ``CONFIG.memory_budget_bytes`` they spill to ``.tfb`` and re-hydrate
-    at each merge; keyless aggregates fold into python scalars.
+    partials and reassembles at finalize.  Keyless aggregates fold into
+    python scalars.  Keys that ``DenseAcc`` takes fold on the device;
+    should a later chunk's keys not fit its domain (a foreign
+    dictionary), the accumulator becomes one partial block and the
+    stream goes on as for other keys: per-chunk partial blocks
+    registered with the spill manager — under a tight
+    ``CONFIG.memory_budget_bytes`` they spill to ``.tfb`` and
+    re-hydrate at each merge.
     """
 
     def __init__(self, key_names: List[str], specs):
@@ -420,6 +643,9 @@ class StreamAgg:
         self._merge_specs = [
             (pn, _PARTIAL_MERGE[fn], pn) for pn, fn, _ in self.partials
         ]
+        self._first = True  # no keyed chunk seen yet
+        self._dense: Optional[DenseAcc] = None
+        self._dense_rebuilds: List = []  # recompute closures folded in
         self._pending: List = []  # Spillable partial blocks
         self._pending_rebuilds: List = []  # parallel recompute closures
         self._merged = None  # Spillable holding the running merge
@@ -432,10 +658,23 @@ class StreamAgg:
     def _partial_block(self, part: TensorFrame) -> Dict[str, np.ndarray]:
         return {name: part.column(name) for name in self._order}
 
+    def _combined_rebuild(self, rebuilds):
+        """A recompute closure for a block merged from blocks with
+        these closures (recompute each, re-merge), or None unless every
+        one has a closure."""
+        if not rebuilds or any(rb is None for rb in rebuilds):
+            return None
+
+        def rebuild(_rbs=tuple(rebuilds)):
+            parts = [rb()[0] for rb in _rbs]
+            return self._partial_block(self._merge_blocks(parts)), {}
+
+        return rebuild
+
     def add(self, f: TensorFrame, rebuild=None) -> None:
         """Fold one chunk frame in.  ``rebuild`` (optional, zero-arg)
         re-produces the chunk frame from durable inputs; when given,
-        the spilled partial carries a recompute closure, so a corrupt
+        the chunk's partial carries a recompute closure, so a corrupt
         spill block repairs itself instead of failing the query."""
         if f.nrows == 0:
             return
@@ -444,19 +683,42 @@ class StreamAgg:
         if not self.key_names:
             self._add_scalar(f)
             return
-        with _obs.detailed_span("pipeline.partial_agg", rows=f.nrows):
-            part = f.groupby(self.key_names).agg(self.partials)
         block_rebuild = None
         if rebuild is not None:
             def block_rebuild(_rb=rebuild):
                 p = _rb().groupby(self.key_names).agg(self.partials)
                 return self._partial_block(p), {}
+        if self._first:
+            self._first = False
+            self._dense = DenseAcc.open(f, self.key_names, self.partials)
+        if self._dense is not None:
+            if self._dense.accepts(f):
+                self._dense.fold(f)
+                self._dense_rebuilds.append(block_rebuild)
+                STATS["dense_agg_chunks"] += 1
+                return
+            self._leave_dense()
+        with _obs.detailed_span("pipeline.partial_agg", rows=f.nrows):
+            part = f.groupby(self.key_names).agg(self.partials)
         self._pending.append(
             SPILL.register(self._partial_block(part), recompute=block_rebuild)
         )
         self._pending_rebuilds.append(block_rebuild)
         if len(self._pending) >= max(2, int(CONFIG.ooc_merge_every)):
             self._merge()
+
+    def _leave_dense(self) -> None:
+        """Hand the device accumulator to the partial path as one
+        spill-managed block."""
+        from repro.store.spill import SPILL
+
+        block_rebuild = self._combined_rebuild(self._dense_rebuilds)
+        self._pending.append(
+            SPILL.register(self._dense.block(), recompute=block_rebuild)
+        )
+        self._pending_rebuilds.append(block_rebuild)
+        self._dense = None
+        self._dense_rebuilds = []
 
     def _merge_blocks(self, blocks) -> TensorFrame:
         if len(blocks) == 1:
@@ -485,13 +747,7 @@ class StreamAgg:
                 blocks.append(data)
                 h.release()
             merged = self._merge_blocks(blocks)
-            merged_rebuild = None
-            if rebuilds and all(rb is not None for rb in rebuilds):
-                # a merged block rebuilds by recomputing every
-                # contributing partial and re-merging
-                def merged_rebuild(_rbs=tuple(rebuilds)):
-                    parts = [rb()[0] for rb in _rbs]
-                    return self._partial_block(self._merge_blocks(parts)), {}
+            merged_rebuild = self._combined_rebuild(rebuilds)
             from repro.store.spill import SPILL
 
             self._merged = SPILL.register(
@@ -533,13 +789,17 @@ class StreamAgg:
                     v = self._scalars[pns[0]]
                 out[out_name] = np.asarray([v])
             return TensorFrame.from_arrays(out)
-        self._merge()
-        if self._merged is None:
-            return None
-        data, _ = self._merged.get()
-        self._merged.release()
-        self._merged = None
-        mf = TensorFrame.from_arrays(dict(data))
+        if self._dense is not None:
+            mf = self._dense.frame(self._order)
+            self._dense = None
+        else:
+            self._merge()
+            if self._merged is None:
+                return None
+            data, _ = self._merged.get()
+            self._merged.release()
+            self._merged = None
+            mf = TensorFrame.from_arrays(dict(data))
         rename: Dict[str, str] = {}
         for out_name, fn, pns in self.finals:
             if fn == "mean":

@@ -55,17 +55,23 @@ def _syncs_snapshot() -> Dict:
 _obs.metrics.register_group("device", _syncs_snapshot, SYNC_SITES.clear)
 
 
-def to_host(x: jax.Array, site: str) -> np.ndarray:
+def to_host(x, site: str):
     """``np.asarray(x)``: wait for the device, copy ``x`` to the host.
+    A tuple of arrays comes back as a tuple of numpy arrays, in one
+    wait.
 
     The engine's one device->host fetch: ``site`` names the caller in
     the per-site counts and on the ``device.sync`` span, whose time is
-    the host blocked on the device.  It is also the one place that lets
-    a transfer through ``jax.transfer_guard_device_to_host``."""
+    the host blocked on the device.  The counts are of host waits, one
+    per call, not of the arrays a call brings.  It is also the one
+    place that lets a transfer through
+    ``jax.transfer_guard_device_to_host``."""
     SYNC_SITES[site] = SYNC_SITES.get(site, 0) + 1
     with _obs.span("device.sync", site=site), jax.transfer_guard_device_to_host(
         "allow"
     ):
+        if isinstance(x, tuple):
+            return jax.device_get(x)
         return np.asarray(x)
 
 

@@ -31,7 +31,10 @@ from typing import Dict, List, Optional, Type
 
 __all__ = ["FaultRule", "clear", "fault_point", "inject", "sites_hit"]
 
-_LOCK = threading.Lock()
+#: Reentrant: a GC pass can run a weakref finalizer on a thread that
+#: holds it, and spill dirs are deleted from finalizers, which call
+#: ``fault_point("spill.delete")``.
+_LOCK = threading.RLock()
 _RULES: List["FaultRule"] = []
 
 #: Observable injection counters (registered as the ``resilience``

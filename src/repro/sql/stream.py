@@ -9,10 +9,14 @@
 and executes it through ``repro.core.pipeline`` instead of the eager
 lowering: the probe scan streams chunk by chunk (prefetching decode
 while the device computes), each join's build side is lowered eagerly
-ONCE and probed per chunk (``HashBuild``), and the aggregate folds into
-spill-managed partials merged every ``CONFIG.ooc_merge_every`` chunks
-(``StreamAgg``).  Peak memory is bounded by chunk size + build sides +
-the partial pool budget, not by the scan's row count.
+ONCE and probed per chunk (``HashBuild``), and the aggregate folds
+chunk by chunk (``StreamAgg``).  Keys that are non-null dictionary or
+bool columns with a small product of cardinalities fold into dense
+device accumulators fetched once at the end (``DenseAcc``); other keys
+fold into spill-managed partials merged every
+``CONFIG.ooc_merge_every`` chunks.  Peak memory is bounded by chunk
+size + build sides + the dense accumulators or the partial pool
+budget, not by the scan's row count.
 
 Gating (``CONFIG.out_of_core``): ``off`` never streams; ``auto``
 streams when the probe table has at least ``CONFIG.ooc_min_rows`` rows;
@@ -74,8 +78,9 @@ def try_stream_aggregate(
         return None
     if mode == "auto" and src.nrows < CONFIG.ooc_min_rows:
         return None
-    # conservative null gate: partial blocks round-trip through host
-    # dicts, which cannot carry key/value nulls faithfully yet
+    # conservative null gate: partial blocks (the keys the dense
+    # accumulator does not take) round-trip through host dicts, which
+    # cannot carry key/value nulls faithfully yet
     for c in cur.columns:
         if src.columns[c].has_validity():
             return None

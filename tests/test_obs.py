@@ -344,6 +344,7 @@ SYNC_SITES = {
     "join.left_outer",
     "join.sorted_probe",
     "join.unique_test",
+    "pipeline.dense_agg",
     "pipeline.probe_chunk",
     "pipeline.unique_test",
     "prims.bounds",

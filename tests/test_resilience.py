@@ -9,7 +9,9 @@ typed shutdown, worker-crash restart), spill write-failure retention
 and corrupt-block recomputation, and the compile-failure negative
 cache.  ``test_chaos.py`` composes them under randomized fault storms.
 """
+import threading
 import time
+import weakref
 
 import numpy as np
 import pytest
@@ -129,6 +131,33 @@ def test_fault_injection_delay():
         faults.fault_point("z")  # sleeps instead of raising
         assert time.perf_counter() - t0 >= 0.04
     assert faults.STATS["delayed"].get("z") == 1
+
+
+def test_fault_point_reentered_by_a_finalizer():
+    """A GC pass can run a weakref finalizer while ``fault_point`` holds
+    the registry lock, and spill dirs are deleted from finalizers that
+    call ``fault_point("spill.delete")``: the re-entry must not
+    deadlock."""
+
+    class Box:
+        pass
+
+    with faults.inject("spill.delete", rate=0.0), faults.inject("w", rate=0.0) as rule:
+        box = Box()
+        weakref.finalize(box, faults.fault_point, "spill.delete")
+        roll = rule._roll
+
+        def roll_and_collect():
+            nonlocal box
+            box = None  # the finalizer runs here, under the lock
+            return roll()
+
+        rule._roll = roll_and_collect
+        t = threading.Thread(target=faults.fault_point, args=("w",), daemon=True)
+        t.start()
+        t.join(timeout=10)
+        assert not t.is_alive()
+        assert box is None
 
 
 # ----------------------------------------------------------------------
